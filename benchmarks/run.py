@@ -23,6 +23,7 @@ import sys
 import time
 
 from repro import telemetry
+from repro.compile_cache import enable_compile_cache
 
 # every emitted row, mirrored as dicts so --json can persist the run as a
 # machine-readable artifact (the CI uploads it per-PR)
@@ -423,6 +424,7 @@ def main() -> None:
                     help="enable span tracing and export a Chrome-trace "
                          "(Perfetto) JSON of the whole run")
     args = ap.parse_args()
+    enable_compile_cache()
     picks = [s for s in args.only.split(",") if s] or list(BENCHES)
     if args.trace:
         telemetry.enable()

@@ -50,8 +50,20 @@ def _dataset(seed: int, ny: int, nx: int, n_hist: int):
     return _DATASETS[key]
 
 
+def _dataset_operands(*, seed: int, ny: int, nx: int, n_hist: int,
+                      k: int) -> Dict[str, object]:
+    """The dataset arrays :func:`_analog_values_batched` indexes, handed to
+    the fusion engine as ``operands``: a sharded round then takes them as
+    replicated program inputs instead of compile-time constants (the full
+    NAM-grid history is over a gigabyte)."""
+    data = _dataset(seed, ny, nx, n_hist)
+    return {"forecast_now": data.forecast_now,
+            "hist_forecast": data.hist_forecast, "hist_obs": data.hist_obs}
+
+
 def _analog_values_batched(locations, *, seed: int, ny: int, nx: int,
-                           n_hist: int, k: int):
+                           n_hist: int, k: int, forecast_now, hist_forecast,
+                           hist_obs):
     """Hand-batched implementation for the fusion engine: one dispatch for
     a whole micro-batch of members.
 
@@ -59,32 +71,31 @@ def _analog_values_batched(locations, *, seed: int, ny: int, nx: int,
     location slices. The member axis folds into the location axis (every
     location is independent), the similarity matrix runs through the
     Pallas distance kernel, and the analog means unfold back to (B, n).
-
-    Traceability: the dataset fields go through ``jnp.asarray`` before the
-    gather so the whole function jits — the SPMD sharded path runs it under
-    ``jit(shard_map(...))`` with ``locations`` a tracer, and numpy arrays
-    cannot be fancy-indexed by tracers.
+    The dataset arrives as arguments (see :func:`_dataset_operands`), so
+    the function also traces under the SPMD path's ``jit(shard_map(...))``.
+    The kernel runs compiled on a TPU and in interpret mode on any other
+    backend.
     """
     import jax
     import jax.numpy as jnp
     from ...kernels.anen_distance import anen_distance
 
-    data = _dataset(seed, ny, nx, n_hist)
     b, n, _ = locations.shape
     flat = locations.reshape(b * n, 2)
     ys, xs = flat[:, 0], flat[:, 1]
-    f_now = jnp.asarray(data.forecast_now)[:, ys, xs]    # (V, B·n)
-    f_h = jnp.asarray(data.hist_forecast)[:, :, ys, xs]  # (H, V, B·n)
-    o_h = jnp.asarray(data.hist_obs)[:, ys, xs]          # (H, B·n)
-    interpret = jax.default_backend() == "cpu"
-    d2 = anen_distance(f_h, f_now, interpret=interpret)
+    f_now = forecast_now[:, ys, xs]                 # (V, B·n)
+    f_h = hist_forecast[:, :, ys, xs]               # (H, V, B·n)
+    o_h = hist_obs[:, ys, xs]                       # (H, B·n)
+    d2 = anen_distance(f_h, f_now,
+                       interpret=jax.default_backend() != "tpu")
     _, idx = jax.lax.top_k(-d2.T, k)                # (B·n, k) most similar
     picked = jnp.take_along_axis(o_h.T, idx, axis=1)
     return picked.mean(axis=1).reshape(b, n)
 
 
 @fusable(static_argnames=("seed", "ny", "nx", "n_hist", "k"),
-         pad_argnames=("locations",), batched=_analog_values_batched)
+         pad_argnames=("locations",), batched=_analog_values_batched,
+         operands=_dataset_operands)
 def analog_values(locations: List[List[int]], seed: int = 0, ny: int = 48,
                   nx: int = 48, n_hist: int = 120, k: int = 12):
     """EnTK task: analog predictions at a slice of locations — the fused
@@ -319,20 +330,28 @@ class _SearchState:
 
 def _run(method: str, seed: int, *, ny: int, nx: int, n_hist: int,
          per_iter: int, max_iters: int, n_tasks: int, slots: int,
-         timeout: float, fuse: bool = True, shard: bool = True) -> Dict:
+         timeout: float, fuse: bool = True, shard: bool = True,
+         devices=None) -> Dict:
+    """One campaign; ``devices`` pins the JaxRTS inventory (default: every
+    device JAX sees). The result carries the runtime that ran it (``rts``)
+    and every computed analog with its location."""
     cfg = AnEnConfig(ny=ny, nx=nx, n_hist=n_hist, seed=seed)
     search = _SearchState(method, seed, cfg, per_iter, max_iters, n_tasks,
                           fuse=fuse)
+    holder: Dict[str, JaxRTS] = {}
+
+    def make_rts() -> JaxRTS:
+        # the fused path: congruent analog members of one round batch into
+        # a single dispatch on the device pool (fuse=False or a LocalRTS
+        # factory reproduces the per-task scalar behaviour bit-for-bit). On
+        # a multi-device pool a wide round shards across the whole mesh
+        # (shard=False opts out)
+        holder["rts"] = JaxRTS(devices=devices, slot_oversubscribe=slots,
+                               shard=shard)
+        return holder["rts"]
+
     amgr = AppManager(resources=ResourceDescription(slots=slots),
-                      # the fused path: congruent analog members of one
-                      # round batch into a single dispatch on the device
-                      # pool (fuse=False or a LocalRTS factory reproduces
-                      # the per-task scalar behaviour bit-for-bit). On a
-                      # multi-device pool a wide round shards across the
-                      # whole mesh (shard=False opts out)
-                      rts_factory=lambda: JaxRTS(slot_oversubscribe=slots,
-                                                 shard=shard),
-                      heartbeat_interval=1.0)
+                      rts_factory=make_rts, heartbeat_interval=1.0)
     compiled = api.compile(search.as_loop(), name=f"anen-{method}-{seed}")
     amgr.workflow = compiled
     amgr.run(timeout=timeout)
@@ -346,7 +365,9 @@ def _run(method: str, seed: int, *, ny: int, nx: int, n_hist: int,
             "n_locations": len(search.locations),
             "rounds": search.iteration,
             "errors": search.errors, "final_rmse": search.errors[-1],
-            "all_done": amgr.all_done}
+            "all_done": amgr.all_done,
+            "locations": search.locations, "values": search.values,
+            "rts": holder.get("rts")}
 
 
 def run_adaptive(seed: int = 0, **kw) -> Dict:

@@ -164,15 +164,21 @@ def run_misfit_chain(n_events: int, slots: int = 4, *, nx: int = 64,
     ``chain=False`` runs the identical 2-stage description per-stage-fused;
     ``fuse=False`` runs it member-per-task — the parity baselines. On a
     multi-device pool a wide event ensemble shards its chain across the
-    whole mesh; ``shard=False`` pins it to per-device micro-batches."""
+    whole mesh; ``shard=False`` pins it to per-device micro-batches. The
+    result carries the runtime that ran the sweep (``rts``)."""
     ens = build_misfit_chain(n_events, nx=nx, nz=nx, nt=nt, seed=seed,
                              dv=dv, fuse=fuse)
     objective = api.gather(ens, total_misfit, name=f"total-chain-{seed}")
+    holder: Dict[str, JaxRTS] = {}
+
+    def make_rts() -> JaxRTS:
+        holder["rts"] = JaxRTS(slot_oversubscribe=slots, shard=shard)
+        return holder["rts"]
+
     t0 = time.time()
     result = api.run(
         objective, resources=ResourceDescription(slots=slots),
-        rts_factory=lambda: JaxRTS(slot_oversubscribe=slots, shard=shard),
-        chain=chain, shard=shard, timeout=timeout)
+        rts_factory=make_rts, chain=chain, shard=shard, timeout=timeout)
     elapsed = time.time() - t0
     out = {
         "n_events": n_events,
@@ -182,6 +188,7 @@ def run_misfit_chain(n_events: int, slots: int = 4, *, nx: int = 64,
         "total_misfit": objective.out.result(),
         "misfits": [float(np.asarray(s.out.result())) for s in ens.specs],
         "wallclock_s": elapsed,
+        "rts": holder.get("rts"),
     }
     result.close()
     return out

@@ -40,7 +40,7 @@ kernel) executes under ``shard_map`` — ONE XLA program spans every leased
 device, chain intermediates stay sharded end-to-end between links, and the
 fan-out hands members sharding-aware lazy slices (a per-member read touches
 one device's shard, never a batch gather). Every sharded wrapper passes
-``check_rep=False``: user kernels may contain ``pallas_call``, which has no
+``check_vma=False``: user kernels may contain ``pallas_call``, which has no
 replication rule. Any sharded-dispatch failure degrades through the
 existing ladder (per-stage fused on one device, then per-member scalar).
 
@@ -241,7 +241,7 @@ def _partition(fn: Callable, spec: FusionSpec, kwargs0: dict
 
 
 def _prepare(calls: Sequence[Tuple[Callable, list, dict]],
-             pad_to: Optional[int] = None):
+             pad_to: Optional[int] = None, device: Any = None):
     """Validate congruence and stack the batch kwargs.
 
     Returns ``(fn, spec, static_kw, shared_kw, stacked, valid_lens, padded_b)``
@@ -249,7 +249,9 @@ def _prepare(calls: Sequence[Tuple[Callable, list, dict]],
     ``padded_b`` (the batch axis bucketed to a power of two, or to
     ``pad_to`` when a chain entry already fixed the bucket) and
     ``valid_lens`` is the per-member unpadded length (None when no padding
-    was needed).
+    was needed). ``shared_kw`` includes the kernel's ``operands``. With
+    ``device`` (a single-device carrier's lease) every stacked and shared
+    array is placed there, so the dispatch runs on the leased device.
     """
     import jax
     import jax.numpy as jnp
@@ -296,6 +298,9 @@ def _prepare(calls: Sequence[Tuple[Callable, list, dict]],
         # exactly the per-task overhead fusion exists to remove
         xp = jnp if any(isinstance(v, jax.Array) for v in raw) else np
         leaves = [xp.asarray(v) for v in raw]
+        if xp is jnp and device is not None:
+            # upstream members may have run on other devices' carriers
+            leaves = jax.device_put(leaves, device)
         shapes = {leaf.shape for leaf in leaves}
         if len(shapes) > 1:
             if k not in spec.pad_argnames:
@@ -336,6 +341,13 @@ def _prepare(calls: Sequence[Tuple[Callable, list, dict]],
             xp = jnp if not isinstance(arr, np.ndarray) else np
             stacked[k] = xp.concatenate(
                 [arr, xp.repeat(arr[-1:], target_b - b, axis=0)])
+    if spec.operands is not None:
+        shared_kw.update(spec.operands(**static_kw))
+    if device is not None:
+        stacked = jax.device_put(stacked, device)
+        shared_kw = {k: jax.device_put(v, device)
+                     if isinstance(v, jax.Array) else v
+                     for k, v in shared_kw.items()}
     return fn0, spec, static_kw, shared_kw, stacked, valid_lens, target_b
 
 
@@ -472,6 +484,17 @@ def build_mesh(devices: Optional[Sequence[Any]]):
         return None
 
 
+def lease_device(devices: Optional[Sequence[Any]]):
+    """The device a single-device carrier runs on: the first device of its
+    lease, or None when the lease holds placeholder names (unit-test pools)
+    and placement is left to JAX's default device."""
+    if not devices:
+        return None
+    import jax
+
+    return devices[0] if isinstance(devices[0], jax.Device) else None
+
+
 def shard_pad(n_members: int, n_shards: int) -> int:
     """Padded batch axis for a sharded dispatch: ``n_shards`` equal shards,
     each bucketed to a power of two — the compile-shape bucketing rule of
@@ -524,15 +547,14 @@ def _composed_segment(plans: Sequence[_LinkPlan], mesh=None) -> Callable:
         return _jit_cached(("chain", cache_key) if cache_key else None,
                            lambda: jax.jit(seg))
 
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     def build():
-        # check_rep=False: links may contain pallas_call (no replication
+        # check_vma=False: links may contain pallas_call (no replication
         # rule); out_specs is a pytree prefix over every link's output
-        return jax.jit(shard_map(
+        return jax.jit(jax.shard_map(
             seg, mesh=mesh, in_specs=(P("m"), P(), P("m")),
-            out_specs=P("m"), check_rep=False))
+            out_specs=P("m"), check_vma=False))
 
     return _jit_cached(
         ("chain-shard", _mesh_key(mesh), cache_key) if cache_key else None,
@@ -685,7 +707,7 @@ def execute_fused(
     try:
         calls = [member_call(t, overrides) for t in live]
         fn, spec, static_kw, shared_kw, stacked, valid_lens, _ = \
-            _prepare(calls)
+            _prepare(calls, device=lease_device(devices))
         t0 = time.perf_counter()
         out = _dispatch(fn, spec, static_kw, shared_kw, stacked)
         out = jax.block_until_ready(out)
@@ -878,8 +900,9 @@ class ChainExecution:
         # device receives an identical block shape from the P('m') split
         entry_pad = None if mesh is None \
             else shard_pad(len(entry_calls), mesh.devices.size)
+        device = None if mesh is not None else lease_device(self.devices)
         fn, spec, static_kw, shared_kw, stacked, valid_lens, padded_b = \
-            _prepare(entry_calls, pad_to=entry_pad)
+            _prepare(entry_calls, pad_to=entry_pad, device=device)
         self._plans[0] = _LinkPlan(self.links[0], fn, spec, static_kw,
                                    shared_kw, stacked, valid_lens, None)
         prev = self.links[0]
@@ -888,7 +911,7 @@ class ChainExecution:
             self._fail_link = j
             calls, carry_name = _continuation_calls(tasks, prev)
             fnj, specj, st_kw, sh_kw, stk, vl, _ = _prepare(
-                calls, pad_to=padded_b)
+                calls, pad_to=padded_b, device=device)
             if vl is None:
                 # a padded axis rides the carry through the whole chain:
                 # downstream links inherit the entry's per-member lengths so
@@ -964,7 +987,6 @@ class ChainExecution:
         invokes the kernel on its own member shard (the kernel's internal
         tiling — e.g. the Pallas grid — applies per shard)."""
         import jax
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
 
         mesh = self._mesh
@@ -974,9 +996,9 @@ class ChainExecution:
         def build():
             def call(kw_, sh_):
                 return batched(**kw_, **sh_, **static_kw)
-            return jax.jit(shard_map(
+            return jax.jit(jax.shard_map(
                 call, mesh=mesh, in_specs=(P("m"), P()),
-                out_specs=P("m"), check_rep=False))
+                out_specs=P("m"), check_vma=False))
 
         cache_key = None if plan.statics_key is None else (
             "shard-batched", _mesh_key(mesh), batched, plan.statics_key,
@@ -1449,8 +1471,9 @@ class DagExecution(ChainExecution):
         entry_calls = [member_call(t) for t in self.links[0]]
         entry_pad = None if mesh is None else shard_pad(
             len(entry_calls), mesh.devices.size)
+        device = None if mesh is not None else lease_device(self.devices)
         fn, spec, static_kw, shared_kw, stacked, valid_lens, padded_b = \
-            _prepare(entry_calls, pad_to=entry_pad)
+            _prepare(entry_calls, pad_to=entry_pad, device=device)
         self._plans[0] = _LinkPlan(self.links[0], fn, spec, static_kw,
                                    shared_kw, stacked, valid_lens, None)
         pad_of = {0: padded_b}       # e-node index -> padded batch axis
@@ -1495,7 +1518,7 @@ class DagExecution(ChainExecution):
                 pad_to = None if mesh is None else shard_pad(
                     len(tasks), mesh.devices.size)
             fnk, speck, st_kw, sh_kw, stk, vl, pb = _prepare(
-                calls, pad_to=pad_to)
+                calls, pad_to=pad_to, device=device)
             if vl is None and meta.carry_name is not None:
                 vl = lens_of[last_e]   # padded rows ride the carry through
             self._plans[k] = _LinkPlan(tasks, fnk, speck, st_kw, sh_kw,
@@ -1615,19 +1638,18 @@ class DagExecution(ChainExecution):
                                  lambda: jax.jit(seg))
             return seg_fn(stacked_list, shared_list, masks, carry, bcast)
 
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
 
         out_specs = [P("m") if mt.role == "e" else P() for mt in metas]
 
         def build():
-            # check_rep=False: node kernels may contain pallas_call (no
+            # check_vma=False: node kernels may contain pallas_call (no
             # replication rule); reductions come back replicated via the
             # in-program psum/pmax, which P() out-specs rely on
-            return jax.jit(shard_map(
+            return jax.jit(jax.shard_map(
                 seg, mesh=mesh,
                 in_specs=(P("m"), P(), P("m"), P("m"), P()),
-                out_specs=out_specs, check_rep=False))
+                out_specs=out_specs, check_vma=False))
 
         seg_fn = _jit_cached(
             ("dag-shard", _mesh_key(mesh), key) if key else None, build)

@@ -67,6 +67,13 @@ class FusionSpec:
 
     ``min_batch`` — per-kernel override of the engine's fuse-vs-scalar
     threshold (None = use the planner default).
+
+    ``operands`` — optional ``operands(**static_kwargs) -> dict`` of arrays
+    the fused dispatch reads that are the same for every member (a dataset
+    the kernel indexes). The engine passes them as shared kwargs, so a
+    sharded program takes them as replicated inputs: an array the kernel
+    closed over instead would be embedded in the compiled program as a
+    constant. The scalar path never receives them.
     """
 
     static_argnames: Sequence[str] = ()
@@ -76,6 +83,7 @@ class FusionSpec:
     check_finite: bool = True
     min_batch: Optional[int] = None
     trim_outputs: bool = True
+    operands: Optional[Callable[..., Dict[str, Any]]] = None
 
 
 def fusable(fn: Optional[Callable[..., Any]] = None, *,
@@ -85,7 +93,9 @@ def fusable(fn: Optional[Callable[..., Any]] = None, *,
             batched: Optional[Callable[..., Any]] = None,
             check_finite: bool = True,
             min_batch: Optional[int] = None,
-            trim_outputs: bool = True) -> Callable[..., Any]:
+            trim_outputs: bool = True,
+            operands: Optional[Callable[..., Dict[str, Any]]] = None
+            ) -> Callable[..., Any]:
     """Mark ``fn`` as a fusion kernel (usable bare or with arguments).
 
     The function itself is unchanged — it still runs scalar anywhere a
@@ -97,7 +107,7 @@ def fusable(fn: Optional[Callable[..., Any]] = None, *,
         shared_argnames=tuple(shared_argnames),
         pad_argnames=tuple(pad_argnames),
         batched=batched, check_finite=check_finite, min_batch=min_batch,
-        trim_outputs=trim_outputs)
+        trim_outputs=trim_outputs, operands=operands)
 
     def mark(f: Callable[..., Any]) -> Callable[..., Any]:
         setattr(f, FUSION_ATTR, spec)

@@ -25,7 +25,7 @@ Validated on CPU with ``interpret=True`` against the jnp reference in
 axis (the member-folded axis in the fused AnEn workflow) is sharded over a
 1-D mesh and each device invokes :func:`anen_distance` — the same Pallas
 block tiling — on its local shard under ``shard_map``
-(``check_rep=False``: pallas_call has no replication rule).
+(``check_vma=False``: pallas_call has no replication rule).
 """
 
 from __future__ import annotations
@@ -36,10 +36,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-# compat: renamed TPUCompilerParams -> CompilerParams in newer jax
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
 
 
 def _distance_kernel(fh_ref, fn_ref, out_ref, *, n_vars: int):
@@ -87,7 +83,7 @@ def anen_distance(f_hist: jnp.ndarray, f_now: jnp.ndarray,
         ],
         out_specs=pl.BlockSpec((block_h, block_n), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((Hp, Np), jnp.float32),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
     )(fh, fn)
@@ -113,7 +109,6 @@ def anen_distance_sharded(f_hist: jnp.ndarray, f_now: jnp.ndarray,
         return anen_distance(f_hist, f_now, interpret=interpret,
                              block_h=block_h, block_n=block_n)
     import numpy as np
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
     H = f_hist.shape[0]
@@ -127,8 +122,8 @@ def anen_distance_sharded(f_hist: jnp.ndarray, f_now: jnp.ndarray,
         return anen_distance(fh_, fn_, interpret=interpret,
                              block_h=block_h, block_n=block_n)
 
-    fn_sharded = jax.jit(shard_map(
+    fn_sharded = jax.jit(jax.shard_map(
         shard, mesh=mesh, in_specs=(P("h"), P()), out_specs=P("h"),
-        check_rep=False))
+        check_vma=False))
     fh = jax.device_put(fh, NamedSharding(mesh, P("h")))
     return fn_sharded(fh, jnp.asarray(f_now))[:H]

@@ -21,10 +21,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# compat: renamed TPUCompilerParams -> CompilerParams in newer jax
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
-
 
 def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, s0_ref,
                 y_ref, sT_ref, state_ref, *, chunk: int):
@@ -123,7 +119,7 @@ def ssd(x: jnp.ndarray, dt: jnp.ndarray, A: jnp.ndarray, Bm: jnp.ndarray,
             jax.ShapeDtypeStruct((B * H, N, P), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((N, P), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(xf, dtf, af, Bh, Ch, s0)

@@ -122,8 +122,6 @@ def compile_cell(arch: str, shape_name: str, multi_pod: bool = False,
 
     mem = compiled.memory_analysis()
     cost = compiled.cost_analysis() or {}
-    if isinstance(cost, (list, tuple)):   # older jax: one dict per device
-        cost = cost[0] if cost else {}
     hlo = analyze(compiled.as_text())
     n_dev = _mesh_devices(mesh)
 

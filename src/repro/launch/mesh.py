@@ -23,11 +23,8 @@ def make_production_mesh(*, multi_pod: bool = False):
     """
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    # AxisType only exists on newer jax; older versions default to Auto
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    kwargs = ({"axis_types": (axis_type.Auto,) * len(axes)}
-              if axis_type is not None else {})
-    return jax.make_mesh(shape, axes, **kwargs)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_mesh_for(devices_or_count, model_axis: int = 1,

@@ -29,13 +29,6 @@ def _compile(fn):
         jax.ShapeDtypeStruct((4, D), jnp.float32)).compile()
 
 
-def _xla_cost(compiled):
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):   # older jax: one dict per device
-        cost = cost[0] if cost else {}
-    return cost
-
-
 def test_scan_flops_match_unrolled():
     a_scan = analyze(_compile(_scan_fn).as_text())
     a_unroll = analyze(_compile(_unrolled_fn).as_text())
@@ -49,7 +42,7 @@ def test_scan_flops_match_unrolled():
 def test_unrolled_matches_xla_cost_analysis():
     c = _compile(_unrolled_fn)
     ours = analyze(c.as_text())["flops"]
-    xla = _xla_cost(c)["flops"]
+    xla = c.cost_analysis()["flops"]
     # elementwise ops are approximated at 1 flop/element; dots dominate
     assert abs(ours - xla) / xla < 0.15
 
@@ -57,7 +50,7 @@ def test_unrolled_matches_xla_cost_analysis():
 def test_xla_undercounts_scan_but_we_dont():
     """Documents the bug this module exists to fix."""
     c = _compile(_scan_fn)
-    xla = _xla_cost(c)["flops"]
+    xla = c.cost_analysis()["flops"]
     ours = analyze(c.as_text())["flops"]
     assert ours > 4 * xla  # XLA counts the 8-trip body once
 

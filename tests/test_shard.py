@@ -504,3 +504,53 @@ def test_sharded_spill_roundtrips_per_shard():
     assert out["rows"] == [2] * 8
     assert out["distinct_files"] == 8      # content-addressed per shard
     assert out["ok_roundtrip"] and out["tamper_caught"]
+
+
+def test_micro_batch_lanes_run_on_their_leased_devices():
+    # without a mesh, each micro-batch carrier places its stacked inputs on
+    # its own leased device; a downstream stage then stacks member values
+    # that live on different devices
+    out = _run_subprocess("""
+        import json
+        import numpy as np
+        from repro import api
+        from repro.fusion import fusable
+        from repro.rts.base import ResourceDescription
+        from repro.rts.jax_rts import JaxRTS
+
+        @fusable
+        def kvec(x):
+            import jax.numpy as jnp
+            return jnp.full((4,), x, jnp.float32)
+
+        @fusable
+        def kdouble(v):
+            import jax.numpy as jnp
+            return jnp.asarray(v) * 2.0
+
+        holder = {}
+        def factory():
+            holder["rts"] = JaxRTS(slot_oversubscribe=1, shard=False)
+            return holder["rts"]
+        first = api.ensemble(kvec, over=[{"x": float(i)} for i in range(64)],
+                             name="v")
+        second = first.then(kdouble, name="d")
+        res = api.run(second, resources=ResourceDescription(slots=8),
+                      rts_factory=factory, chain=False, shard=False,
+                      timeout=240)
+        devices = {d.id for s in first.specs
+                   for d in s.out.result().value.devices()}
+        vals = [float(np.asarray(s.out.result())[0]) for s in second.specs]
+        stats = dict(holder["rts"].fusion_stats)
+        print(json.dumps({
+            "all_done": res.all_done, "devices": len(devices),
+            "vals_ok": vals == [2.0 * i for i in range(64)],
+            "dispatches": stats["dispatches"],
+            "scalar_fallback": stats["scalar_fallback"],
+            "degraded": stats["degraded"]}))
+        res.close()
+    """)
+    assert out["all_done"] and out["vals_ok"]
+    assert out["devices"] > 1
+    assert out["dispatches"] > 2
+    assert out["scalar_fallback"] == 0 and out["degraded"] == 0
