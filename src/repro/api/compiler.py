@@ -37,6 +37,7 @@ import itertools
 import traceback
 from typing import Any, Dict, List, Optional, Sequence, Set, Union
 
+from .. import telemetry as tel
 from ..core import uid
 from ..core.pst import Pipeline, Stage, Task
 from ..core.results import STORE
@@ -861,7 +862,8 @@ class _LoopRuntime:
 
     def round_units(self, k: int, lctx: LoopContext
                     ) -> "tuple[List[TaskSpec], TaskSpec]":
-        node = self.loop.body(lctx)
+        with tel.span("api.loop.body", "api", loop=self.loop.name, round=k):
+            node = self.loop.body(lctx)
         if not isinstance(node, Node):
             raise CompileError(
                 f"repeat_until body for {self.loop.name!r} round {k} must "
@@ -878,8 +880,10 @@ class _LoopRuntime:
         k = len(self.history)
         self.history.append(results)
         lctx = LoopContext(k, results, self.history)
-        stop = bool(self.loop.predicate(lctx)) or (k + 1
-                                                   >= self.loop.max_rounds)
+        with tel.span("api.loop.predicate", "api", loop=self.loop.name,
+                      round=k):
+            stop = bool(self.loop.predicate(lctx))
+        stop = stop or k + 1 >= self.loop.max_rounds
         if stop:
             STORE.put(self.ctx.ns, self.loop.name, results)
             if self.continuation:

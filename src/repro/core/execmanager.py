@@ -395,7 +395,6 @@ class ExecManager:
         with tel.span("emgr.submit", "emgr", tasks=len(submittable)):
             rts.submit(submittable)
         tel.counter("emgr_submit_rounds_total").inc()
-        tel.counter("emgr_submitted_tasks_total").inc(len(submittable))
         self.prof.add(RTS_OVERHEAD, time.perf_counter() - t1)
 
     def _prune_fronts_locked(self) -> None:

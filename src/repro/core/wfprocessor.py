@@ -460,7 +460,6 @@ class WFProcessor:
         if len(published) < 2:
             return
         tel.counter("wfp_superstages_total").inc()
-        tel.histogram("wfp_superstage_stages").observe(len(published))
         # stamp the superstage EXTENT ("ss" = highest co-published link per
         # chain) onto every published link task: the Emgr only holds a
         # chain fragment for links it knows were co-published — a chain

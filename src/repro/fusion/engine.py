@@ -240,8 +240,31 @@ def _partition(fn: Callable, spec: FusionSpec, kwargs0: dict
     return part
 
 
+def _placed_nbytes(value: Any, target: Any) -> int:
+    """Bytes of ``value``'s array leaves that placing them on ``target`` (a
+    sharding, or None for JAX's default device) moves: host arrays, and
+    device arrays laid out otherwise (a copy on each of the target's
+    devices, in any order, is in place). A leaf counts once, however many
+    devices it is replicated to."""
+    import jax
+
+    total = 0
+    for leaf in jax.tree_util.tree_leaves(value):
+        if not isinstance(leaf, jax.Array):
+            total += np.asarray(leaf).nbytes
+            continue
+        have = leaf.sharding
+        if target is None or have.is_equivalent_to(target, leaf.ndim) or (
+                have.is_fully_replicated and target.is_fully_replicated
+                and have.device_set == target.device_set):
+            continue
+        total += leaf.nbytes
+    return total
+
+
 def _prepare(calls: Sequence[Tuple[Callable, list, dict]],
-             pad_to: Optional[int] = None, device: Any = None):
+             pad_to: Optional[int] = None, device: Any = None,
+             placed: Optional[List[int]] = None):
     """Validate congruence and stack the batch kwargs.
 
     Returns ``(fn, spec, static_kw, shared_kw, stacked, valid_lens, padded_b)``
@@ -252,9 +275,15 @@ def _prepare(calls: Sequence[Tuple[Callable, list, dict]],
     was needed). ``shared_kw`` includes the kernel's ``operands``. With
     ``device`` (a single-device carrier's lease) every stacked and shared
     array is placed there, so the dispatch runs on the leased device.
+    ``placed`` (tracing only) gets the bytes that placement moves, counted
+    before it: the caller of a mesh carrier counts its own placement.
     """
     import jax
     import jax.numpy as jnp
+
+    target = None
+    if placed is not None and device is not None:
+        target = jax.sharding.SingleDeviceSharding(device)
 
     fn0, args0, kwargs0 = calls[0]
     spec = fusion_spec(fn0)
@@ -300,6 +329,8 @@ def _prepare(calls: Sequence[Tuple[Callable, list, dict]],
         leaves = [xp.asarray(v) for v in raw]
         if xp is jnp and device is not None:
             # upstream members may have run on other devices' carriers
+            if placed is not None:
+                placed.append(_placed_nbytes(leaves, target))
             leaves = jax.device_put(leaves, device)
         shapes = {leaf.shape for leaf in leaves}
         if len(shapes) > 1:
@@ -343,6 +374,8 @@ def _prepare(calls: Sequence[Tuple[Callable, list, dict]],
                 [arr, xp.repeat(arr[-1:], target_b - b, axis=0)])
     if spec.operands is not None:
         shared_kw.update(spec.operands(**static_kw))
+    if placed is not None:
+        placed.append(_placed_nbytes((stacked, shared_kw), target))
     if device is not None:
         stacked = jax.device_put(stacked, device)
         shared_kw = {k: jax.device_put(v, device)
@@ -894,36 +927,14 @@ class ChainExecution:
             self._push(("degrade", 0, None))
             return
         self._fail_link = 0
-        entry_calls = [member_call(t) for t in self.links[0]]
         mesh = self._mesh
-        # a sharded batch pads to n_shards equal pow2 shards so every mesh
-        # device receives an identical block shape from the P('m') split
-        entry_pad = None if mesh is None \
-            else shard_pad(len(entry_calls), mesh.devices.size)
-        device = None if mesh is not None else lease_device(self.devices)
-        fn, spec, static_kw, shared_kw, stacked, valid_lens, padded_b = \
-            _prepare(entry_calls, pad_to=entry_pad, device=device)
-        self._plans[0] = _LinkPlan(self.links[0], fn, spec, static_kw,
-                                   shared_kw, stacked, valid_lens, None)
-        prev = self.links[0]
-        inherited = valid_lens
-        for j, tasks in enumerate(self.links[1:], start=1):
-            self._fail_link = j
-            calls, carry_name = _continuation_calls(tasks, prev)
-            fnj, specj, st_kw, sh_kw, stk, vl, _ = _prepare(
-                calls, pad_to=padded_b, device=device)
-            if vl is None:
-                # a padded axis rides the carry through the whole chain:
-                # downstream links inherit the entry's per-member lengths so
-                # their delivered values trim exactly like the scalar path's
-                vl = inherited
-            else:
-                inherited = vl
-            self._plans[j] = _LinkPlan(tasks, fnj, specj, st_kw, sh_kw, stk,
-                                       vl, carry_name)
-            prev = tasks
-        if mesh is not None:
-            self._place_plans(mesh)
+        with tel.span("fusion.prepare", "fusion") as prep:
+            placed = None if prep is tel.NOOP_SPAN else []
+            self._prepare_links(placed)
+            if mesh is not None:
+                self._place_plans(mesh, placed)
+            if placed is not None:
+                prep.set(placed_bytes=sum(placed), rows=self._rows())
         # dispatch: maximal runs of vmap-able links compose into ONE jitted
         # program; a hand-written batched impl executes eagerly between
         # segments (its jnp ops still enqueue asynchronously). Under a mesh
@@ -967,7 +978,59 @@ class ChainExecution:
             carry = outs[-1]
             idx = j
 
-    def _place_plans(self, mesh) -> None:
+    def _prepare_links(self, placed: Optional[List[int]]) -> None:
+        """Resolve and stack every link's members into its plan; a
+        single-device carrier places them on its leased device."""
+        entry_calls = [member_call(t) for t in self.links[0]]
+        mesh = self._mesh
+        # a sharded batch pads to n_shards equal pow2 shards so every mesh
+        # device receives an identical block shape from the P('m') split
+        entry_pad = None if mesh is None \
+            else shard_pad(len(entry_calls), mesh.devices.size)
+        device = None if mesh is not None else lease_device(self.devices)
+        placed_here = placed if mesh is None else None
+        fn, spec, static_kw, shared_kw, stacked, valid_lens, padded_b = \
+            _prepare(entry_calls, pad_to=entry_pad, device=device,
+                     placed=placed_here)
+        self._plans[0] = _LinkPlan(self.links[0], fn, spec, static_kw,
+                                   shared_kw, stacked, valid_lens, None)
+        prev = self.links[0]
+        inherited = valid_lens
+        for j, tasks in enumerate(self.links[1:], start=1):
+            self._fail_link = j
+            calls, carry_name = _continuation_calls(tasks, prev)
+            fnj, specj, st_kw, sh_kw, stk, vl, _ = _prepare(
+                calls, pad_to=padded_b, device=device, placed=placed_here)
+            if vl is None:
+                # a padded axis rides the carry through the whole chain:
+                # downstream links inherit the entry's per-member lengths so
+                # their delivered values trim exactly like the scalar path's
+                vl = inherited
+            else:
+                inherited = vl
+            self._plans[j] = _LinkPlan(tasks, fnj, specj, st_kw, sh_kw, stk,
+                                       vl, carry_name)
+            prev = tasks
+
+    def _rows(self) -> int:
+        """Member rows stacked over the carrier's plans (unpadded)."""
+        return sum(len(p.tasks) for p in self._plans if p is not None)
+
+    @staticmethod
+    def _count_mesh_placement(plan: _LinkPlan, mesh,
+                              placed: Optional[List[int]]) -> None:
+        """Bytes a mesh carrier moves for one plan: stacked kwargs split on
+        the member axis, shared kwargs replicated (the program's call places
+        those, as its in-specs ask)."""
+        if placed is None:
+            return
+        from jax.sharding import NamedSharding, PartitionSpec as P
+
+        placed.append(_placed_nbytes(plan.stacked, NamedSharding(mesh, P("m")))
+                      + _placed_nbytes(plan.shared_kw,
+                                       NamedSharding(mesh, P())))
+
+    def _place_plans(self, mesh, placed: Optional[List[int]] = None) -> None:
         """Place every link's stacked kwargs across the mesh member axis
         (shared kwargs replicate). Raises on unplaceable leaves — caught by
         :meth:`dispatch`, which degrades to the micro-batch ladder."""
@@ -977,6 +1040,7 @@ class ChainExecution:
 
         sharded = NamedSharding(mesh, P("m"))
         for plan in self._plans:
+            self._count_mesh_placement(plan, mesh, placed)
             plan.stacked = {k: jax.device_put(v, sharded)
                             for k, v in plan.stacked.items()}
             plan.shared_kw = jax.tree_util.tree_map(
@@ -1404,10 +1468,17 @@ class DagExecution(ChainExecution):
             # ordering, so the reduce never races its members' routing
             self._push(("degrade", 0, None))
             return
-        self._prepare_nodes()
         mesh = self._mesh
-        if mesh is not None:
-            self._place_dag(mesh)
+        with tel.span("fusion.prepare", "fusion") as prep:
+            placed = None if prep is tel.NOOP_SPAN else []
+            self._prepare_nodes(placed)
+            if placed is not None:
+                # the reduction masks are built on the host
+                placed.append(_placed_nbytes(self._masks, None))
+            if mesh is not None:
+                self._place_dag(mesh, placed)
+            if placed is not None:
+                prep.set(placed_bytes=sum(placed), rows=self._rows())
         idx = 0
         carry = None
         bcast = None
@@ -1455,12 +1526,13 @@ class DagExecution(ChainExecution):
                     bcast = out
             idx = j
 
-    def _prepare_nodes(self) -> None:
+    def _prepare_nodes(self, placed: Optional[List[int]] = None) -> None:
         """Build every node's plan, reduction mask and combine; raises
         :class:`Incongruent` on any unsupported shape — caught by
         :meth:`dispatch`, which degrades the WHOLE DAG to sequential
         per-node execution (prep happens before any dispatch)."""
         mesh = self._mesh
+        placed_here = placed if mesh is None else None
         if self._meta[0].role != "e":
             raise Incongruent("DAG does not start at an ensemble node")
         if mesh is not None:
@@ -1473,7 +1545,8 @@ class DagExecution(ChainExecution):
             len(entry_calls), mesh.devices.size)
         device = None if mesh is not None else lease_device(self.devices)
         fn, spec, static_kw, shared_kw, stacked, valid_lens, padded_b = \
-            _prepare(entry_calls, pad_to=entry_pad, device=device)
+            _prepare(entry_calls, pad_to=entry_pad, device=device,
+                     placed=placed_here)
         self._plans[0] = _LinkPlan(self.links[0], fn, spec, static_kw,
                                    shared_kw, stacked, valid_lens, None)
         pad_of = {0: padded_b}       # e-node index -> padded batch axis
@@ -1518,7 +1591,7 @@ class DagExecution(ChainExecution):
                 pad_to = None if mesh is None else shard_pad(
                     len(tasks), mesh.devices.size)
             fnk, speck, st_kw, sh_kw, stk, vl, pb = _prepare(
-                calls, pad_to=pad_to, device=device)
+                calls, pad_to=pad_to, device=device, placed=placed_here)
             if vl is None and meta.carry_name is not None:
                 vl = lens_of[last_e]   # padded rows ride the carry through
             self._plans[k] = _LinkPlan(tasks, fnk, speck, st_kw, sh_kw,
@@ -1548,7 +1621,7 @@ class DagExecution(ChainExecution):
             mask[i] = k_inj is None or k_inj > src
         return mask
 
-    def _place_dag(self, mesh) -> None:
+    def _place_dag(self, mesh, placed: Optional[List[int]] = None) -> None:
         """Place every ensemble node's stacked kwargs and every reduction
         mask across the mesh member axis (shared kwargs replicate)."""
         import jax
@@ -1561,6 +1634,7 @@ class DagExecution(ChainExecution):
                 if self._masks[k] is not None:
                     self._masks[k] = jax.device_put(self._masks[k], sharded)
                 continue
+            self._count_mesh_placement(plan, mesh, placed)
             plan.stacked = {kk: jax.device_put(v, sharded)
                             for kk, v in plan.stacked.items()}
             plan.shared_kw = jax.tree_util.tree_map(

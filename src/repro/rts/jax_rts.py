@@ -1125,15 +1125,17 @@ class JaxRTS(LocalRTS):
         # early links of a chain while a later link is still dispatching
         # (mid-chain journal records exist the moment a link resolves)
         self._drain_q.put((carrier, batch, exe))
-        with tel.span("carrier.dispatch", "rts",
-                      carrier=carrier.name, tier=exe.tier,
-                      links=len(batch.links),
-                      width=max(len(link) for link in batch.links),
-                      members=len(batch.members),
-                      mesh_shards=batch.mesh_shards,
-                      tenants=",".join(sorted(
-                          str(t) for t in set(tenant_of.values())
-                          if t is not None))):
+        span = tel.NOOP_SPAN
+        if tel.enabled():
+            span = tel.span(
+                "carrier.dispatch", "rts", carrier=carrier.name,
+                tier=exe.tier, links=len(batch.links),
+                width=max(len(link) for link in batch.links),
+                members=len(batch.members), mesh_shards=batch.mesh_shards,
+                tenants=",".join(sorted(
+                    str(t) for t in set(tenant_of.values())
+                    if t is not None)))
+        with span:
             exe.dispatch()
 
     def _record_breaker(self, batch: _FusedBatch, exe: Any, ok: bool) -> None:

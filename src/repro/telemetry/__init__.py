@@ -14,7 +14,10 @@ Two planes with different cost contracts:
   zero-cost when off: :func:`span` returns a shared no-op singleton, so an
   instrumentation point costs one flag check. Enable with :func:`enable`,
   ``REPRO_TELEMETRY=1`` in the environment, or ``--trace`` on
-  ``benchmarks/run.py``.
+  ``benchmarks/run.py``. While enabled, each span is mirrored as a
+  ``jax.profiler.TraceAnnotation`` of its name when JAX was imported
+  before :func:`enable` (this package never imports JAX), so a running
+  JAX profiler session shows the program's spans beside the device ops.
 
 Exports: :func:`export_chrome_trace` (Perfetto-loadable JSON),
 :func:`prometheus_text` (the serve protocol's ``metrics`` verb),
@@ -24,6 +27,7 @@ Exports: :func:`export_chrome_trace` (Perfetto-loadable JSON),
 from __future__ import annotations
 
 import os
+import sys
 from typing import Any, Dict, List, Optional
 
 from . import export as _export
@@ -52,12 +56,20 @@ def enable(ring_size: Optional[int] = None) -> None:
     global _enabled, TRACER
     if ring_size is not None and ring_size != TRACER.ring_size:
         TRACER = SpanTracer(ring_size=ring_size)
+    TRACER.mirror = _profiler_annotation()
     _enabled = True
 
 
 def disable() -> None:
     global _enabled
     _enabled = False
+    TRACER.mirror = None
+
+
+def _profiler_annotation():
+    """``jax.profiler.TraceAnnotation`` where JAX is already imported."""
+    jax = sys.modules.get("jax")
+    return getattr(getattr(jax, "profiler", None), "TraceAnnotation", None)
 
 
 def enabled() -> bool:

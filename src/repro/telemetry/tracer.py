@@ -17,6 +17,15 @@ Design constraints (ISSUE 9):
 Timestamps are ``time.monotonic_ns()`` — immune to wall-clock steps, and
 exactly what the Chrome-trace ``ts``/``dur`` microsecond fields want after
 a ``/1000``.
+
+**Profiler mirror.** A tracer with a ``mirror`` (``jax.profiler.
+TraceAnnotation``, bound by :func:`repro.telemetry.enable` when JAX is
+already imported) enters one annotation of the span's name as the span
+opens and exits it in :meth:`Span.end`, so a running JAX profiler session
+records every span on the host plane, on the device trace's own clock.
+The profiler records an annotation on the thread that exits it, so a span
+ended on another thread than the one that opened it is not mirrored: its
+annotation is parked, never exited (dropping it would record it too).
 """
 
 from __future__ import annotations
@@ -24,7 +33,7 @@ from __future__ import annotations
 import threading
 import time
 from collections import deque
-from typing import Any, Dict, List, Optional
+from typing import Any, Callable, Dict, List, Optional
 
 DEFAULT_RING_SIZE = 65_536
 
@@ -54,7 +63,7 @@ class Span:
     """One open span; closes via :meth:`end` or as a context manager."""
 
     __slots__ = ("_tracer", "name", "cat", "attrs", "t0", "tid", "thread",
-                 "depth", "_done")
+                 "depth", "_done", "_mirror")
 
     def __init__(self, tracer: "SpanTracer", name: str, cat: str,
                  attrs: Dict[str, Any]) -> None:
@@ -67,6 +76,10 @@ class Span:
         self.thread = cur.name
         self.depth = tracer._push_depth()
         self._done = False
+        self._mirror = None
+        if tracer.mirror is not None:
+            self._mirror = tracer.mirror(name)
+            self._mirror.__enter__()
         self.t0 = time.monotonic_ns()
 
     def set(self, **attrs: Any) -> "Span":
@@ -78,6 +91,12 @@ class Span:
             return
         self._done = True
         dur = time.monotonic_ns() - self.t0
+        if self._mirror is not None:
+            if threading.get_ident() == self.tid:
+                self._mirror.__exit__(None, None, None)
+            else:
+                self._tracer._parked.append(self._mirror)
+            self._mirror = None
         self._tracer._pop_depth()
         self._tracer._record({
             "ph": "X", "name": self.name, "cat": self.cat, "ts": self.t0,
@@ -98,6 +117,12 @@ class SpanTracer:
 
     def __init__(self, ring_size: int = DEFAULT_RING_SIZE) -> None:
         self.ring_size = max(1, ring_size)
+        #: ``name -> context manager`` entered as each span opens (the
+        #: profiler mirror), or None
+        self.mirror: Optional[Callable[[str], Any]] = None
+        # mirrors of spans ended on a foreign thread, kept so they are
+        # never recorded (see the module docstring)
+        self._parked: List[Any] = []
         self._ring: deque = deque()
         self._lock = threading.Lock()
         self._local = threading.local()
