@@ -118,17 +118,20 @@ def compute_analogs(data: AnEnData, locations: jnp.ndarray, k: int
 
 @functools.partial(jax.jit, static_argnames=("ny", "nx", "power",
                                              "k_nearest", "eps"))
-def idw_interpolate(locations: jnp.ndarray, values: jnp.ndarray,
-                    ny: int, nx: int, power: float = 2.0,
-                    k_nearest: int = 8, eps: float = 1e-6) -> jnp.ndarray:
-    """k-nearest inverse-distance interpolation onto the full grid.
+def idw_interpolate_nearest(locations: jnp.ndarray, values: jnp.ndarray,
+                            ny: int, nx: int, power: float = 2.0,
+                            k_nearest: int = 8, eps: float = 1e-6
+                            ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """k-nearest inverse-distance interpolation onto the full grid, and
+    each cell's squared distance to its nearest sample, both (ny, nx).
 
     Restricting to the nearest ``k`` samples (the unstructured-grid
     behaviour of the paper's implementation) is what makes *local*
     refinement effective: far-away samples cannot wash out a freshly
     refined front. Jitted so the (grid × samples) distance matrix is built
     once inside one program: op by op, its temporaries would hold three
-    copies of it at once, over 12 GB on the NAM grid at 4096 samples.
+    copies of it at once, over 12 GB on the NAM grid at 4096 samples. The
+    distances are integers below 2²⁴, so exact in float32.
     """
     yy, xx = jnp.mgrid[0:ny, 0:nx]
     gy = yy.reshape(-1).astype(jnp.float32)
@@ -142,7 +145,17 @@ def idw_interpolate(locations: jnp.ndarray, values: jnp.ndarray,
     w = 1.0 / ((-neg_d2) ** (power / 2) + eps)
     vals = values[idx]                               # (G, k)
     est = (w * vals).sum(axis=1) / w.sum(axis=1)
-    return est.reshape(ny, nx)
+    # the top-1 as a max over the k columns: a slice of its own would keep
+    # XLA from rewriting the sort under ``top_k`` into a partial TopK
+    return est.reshape(ny, nx), (-neg_d2.max(axis=1)).reshape(ny, nx)
+
+
+def idw_interpolate(locations: jnp.ndarray, values: jnp.ndarray,
+                    ny: int, nx: int, power: float = 2.0,
+                    k_nearest: int = 8, eps: float = 1e-6) -> jnp.ndarray:
+    """The estimate of :func:`idw_interpolate_nearest` alone."""
+    return idw_interpolate_nearest(locations, values, ny, nx, power,
+                                   k_nearest, eps)[0]
 
 
 def rmse(a: jnp.ndarray, b: jnp.ndarray) -> float:

@@ -25,12 +25,13 @@ from typing import Dict, List
 import numpy as np
 
 from ... import api
+from ... import telemetry as tel
 from ...core import AppManager, register_executable
 from ...fusion import fusable, fusable_reduction
 from ...rts.base import ResourceDescription
 from ...rts.jax_rts import JaxRTS
 from .anen import (AnEnConfig, compute_analogs, gradient_magnitude,
-                   idw_interpolate, make_dataset, rmse)
+                   idw_interpolate_nearest, make_dataset, rmse)
 
 _DATASETS: Dict[int, object] = {}
 
@@ -196,6 +197,10 @@ class _SearchState:
         # back as bare value arrays (device-resident on the fused path), so
         # the builder keeps the location bookkeeping host-side
         self._round_slices: List[List[List[int]]] = []
+        # what absorb's IDW made of the samples: (number of samples covered,
+        # the estimate, each cell's squared distance to its nearest sample),
+        # device arrays the AUA proposal reads back instead of redoing them
+        self._idw = None
 
     # ---- location proposal ------------------------------------------------ #
 
@@ -222,36 +227,72 @@ class _SearchState:
         neighbourhood suppression avoids redundant clustering on the same
         front pixel. A quarter of the budget stays uniform (coverage of
         regions the current estimate cannot see yet).
+
+        Incremental, with the picks of the whole-grid greedy: the estimate
+        and the distances are absorb's where they cover the current samples
+        (else interpolated here), and each pick becomes a sample for the
+        next. No distance ever grows, so ``reach = ceil(sqrt(max dist²))``,
+        read before the explore points are folded in, bounds every later
+        change: a new sample lowers no cell outside the box of ±reach
+        around it, and each fold or pick updates only that box and its
+        rows' maxima. On a tie the first cell in row-major order wins (the
+        first row holding the maximum, then the first column in it), as
+        ``np.argmax`` on the flat grid.
         """
-        import jax.numpy as jnp
         n_explore = max(1, n // 4)
         n_exploit = n - n_explore
         explore = self._random_new(n_explore)
         ny, nx = self.cfg.ny, self.cfg.nx
-        locs = jnp.asarray(self.locations, jnp.int32)
-        vals = jnp.asarray(self.values, jnp.float32)
-        est = idw_interpolate(locs, vals, ny, nx)
+        kept = self._idw
+        reused = kept is not None and kept[0] == len(self.locations)
+        est, near = kept[1:] if reused else self._interpolate()
         grad = np.asarray(gradient_magnitude(est)).astype(np.float64)
         # smear the indicator one cell so line-like fronts are 2-3 px wide
         grad = grad + 0.5 * (np.roll(grad, 1, 0) + np.roll(grad, -1, 0)
                              + np.roll(grad, 1, 1) + np.roll(grad, -1, 1))
-        yy, xx = np.mgrid[0:ny, 0:nx]
-        all_pts = (np.asarray(self.locations + explore.tolist())
-                   if len(self.locations) else explore)
-        d2 = np.full((ny, nx), np.inf)
-        for (py, px) in all_pts:
-            d2 = np.minimum(d2, (yy - py) ** 2 + (xx - px) ** 2)
-        picks = []
+        d2 = np.asarray(near).astype(np.float64)
+        reach = int(np.ceil(np.sqrt(d2.max())))
+        iy, ix = np.arange(ny), np.arange(nx)
+        cells = 0
+
+        def visit(py: int, px: int):
+            """Make (py, px) a sample: lower d2 in its box."""
+            nonlocal cells
+            rows = slice(max(0, py - reach), py + reach + 1)
+            cols = slice(max(0, px - reach), px + reach + 1)
+            box = d2[rows, cols]
+            np.minimum(box, (iy[rows, None] - py) ** 2
+                       + (ix[None, cols] - px) ** 2, out=box)
+            cells += box.size
+            return rows, cols
+
+        for py, px in explore.tolist():
+            visit(py, px)
         pri = grad * d2
+        row_max = pri.max(axis=1)
+        picks = []
         for _ in range(n_exploit):
-            flat = int(np.argmax(pri))
-            py, px = flat // nx, flat % nx
+            py = int(np.argmax(row_max))
+            px = int(np.argmax(pri[py]))
             picks.append([py, px])
-            nd2 = (yy - py) ** 2 + (xx - px) ** 2
-            d2 = np.minimum(d2, nd2)
-            pri = grad * d2
+            rows, cols = visit(py, px)
+            pri[rows, cols] = grad[rows, cols] * d2[rows, cols]
+            row_max[rows] = pri[rows].max(axis=1)
+        if tel.enabled():
+            # an instant event: the proposal's time stays the body's own
+            tel.event("anen.propose", "anen", picks=n_exploit,
+                      explore=n_explore, reach=reach, cells_updated=cells,
+                      reused_estimate=int(reused))
         return np.concatenate([explore, np.asarray(picks, np.int32)],
                               axis=0)
+
+    def _interpolate(self):
+        """The IDW estimate of the samples and each cell's squared distance
+        to its nearest sample, as device arrays."""
+        import jax.numpy as jnp
+        locs = jnp.asarray(self.locations, jnp.int32)
+        vals = jnp.asarray(self.values, jnp.float32)
+        return idw_interpolate_nearest(locs, vals, self.cfg.ny, self.cfg.nx)
 
     def propose(self, n: int) -> np.ndarray:
         if self.method == "aua" and self.iteration > 0:
@@ -272,10 +313,8 @@ class _SearchState:
                 continue
             self.locations.extend(slice_locs)
             self.values.extend(np.asarray(r).tolist())
-        import jax.numpy as jnp
-        locs = jnp.asarray(self.locations, jnp.int32)
-        vals = jnp.asarray(self.values, jnp.float32)
-        est = idw_interpolate(locs, vals, self.cfg.ny, self.cfg.nx)
+        est, near = self._interpolate()
+        self._idw = (len(self.locations), est, near)
         self.errors.append(rmse(est, self.data.truth))
         self.iteration += 1
 

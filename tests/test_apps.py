@@ -113,3 +113,162 @@ def test_aua_workflow_completes_and_steers():
     assert r["n_locations"] == 60
     # error is (weakly) improving as locations accumulate
     assert r["errors"][-1] <= r["errors"][0] + 1e-6
+
+
+# ---- the AUA proposal against the whole-grid greedy ----------------------- #
+
+
+def _whole_grid_proposal(st, n):
+    """The AUA proposal as a whole-grid greedy, every distance and priority
+    recomputed over the grid after each pick: the oracle that the
+    incremental proposal has to match pick for pick."""
+    from repro.apps.anen import workflow as w
+    n_explore = max(1, n // 4)
+    n_exploit = n - n_explore
+    explore = st._random_new(n_explore)
+    ny, nx = st.cfg.ny, st.cfg.nx
+    locs = jnp.asarray(st.locations, jnp.int32)
+    vals = jnp.asarray(st.values, jnp.float32)
+    est = idw_interpolate(locs, vals, ny, nx)
+    grad = np.asarray(w.gradient_magnitude(est)).astype(np.float64)
+    grad = grad + 0.5 * (np.roll(grad, 1, 0) + np.roll(grad, -1, 0)
+                         + np.roll(grad, 1, 1) + np.roll(grad, -1, 1))
+    yy, xx = np.mgrid[0:ny, 0:nx]
+    all_pts = (np.asarray(st.locations + explore.tolist())
+               if len(st.locations) else explore)
+    d2 = np.full((ny, nx), np.inf)
+    for (py, px) in all_pts:
+        d2 = np.minimum(d2, (yy - py) ** 2 + (xx - px) ** 2)
+    picks = []
+    pri = grad * d2
+    for _ in range(n_exploit):
+        flat = int(np.argmax(pri))
+        py, px = flat // nx, flat % nx
+        picks.append([py, px])
+        nd2 = (yy - py) ** 2 + (xx - px) ** 2
+        d2 = np.minimum(d2, nd2)
+        pri = grad * d2
+    return np.concatenate([explore, np.asarray(picks, np.int32)], axis=0)
+
+
+def _search_state(seed, ny, nx, per_iter, rounds=2, n_tasks=3, drop=None):
+    """An AUA search state after ``rounds`` absorbed rounds, each placed by
+    the state's own proposal and valued from the truth field; the result of
+    slice ``drop = (round, slice)`` comes back None."""
+    from repro.apps.anen.workflow import _SearchState
+    st = _SearchState("aua", seed, AnEnConfig(ny=ny, nx=nx, n_hist=12,
+                                              seed=seed),
+                      per_iter, max_iters=rounds + 1, n_tasks=n_tasks)
+    truth = np.asarray(st.data.truth)
+    for r in range(rounds):
+        slices = [sl for sl in np.array_split(st.propose(per_iter), n_tasks)
+                  if len(sl)]
+        st._round_slices = [sl.tolist() for sl in slices]
+        st.absorb([None if (r, i) == drop else truth[sl[:, 0], sl[:, 1]]
+                   for i, sl in enumerate(slices)])
+    return st
+
+
+def _against_oracle(st, n):
+    """The incremental proposal and the oracle's, from one state and one
+    position of its random stream."""
+    at = st.rng.bit_generator.state
+    got = st._adaptive_new(n)
+    st.rng.bit_generator.state = at
+    return got, _whole_grid_proposal(st, n)
+
+
+@pytest.mark.parametrize("seed,ny,nx,per_iter,variant", [
+    (0, 24, 24, 20, "plain"), (1, 24, 24, 20, "plain"),
+    (2, 24, 24, 20, "plain"), (0, 48, 64, 64, "plain"),
+    (1, 48, 64, 64, "plain"), (2, 48, 64, 64, "plain"),
+    (0, 24, 24, 20, "ties"), (1, 48, 64, 64, "none_slice"),
+    (2, 48, 64, 64, "stale"), (0, 48, 64, 7, "plain"),
+    (1, 24, 24, 13, "plain"), (2, 24, 24, 3, "plain"),
+])
+def test_aua_proposal_matches_whole_grid_greedy(monkeypatch, seed, ny, nx,
+                                                per_iter, variant):
+    from repro.apps.anen import workflow as w
+    if variant == "ties":
+        # a constant-gradient estimate: priority = squared distance, whose
+        # exact ties only the row-major first-hit rule settles
+        monkeypatch.setattr(w, "gradient_magnitude", jnp.ones_like)
+    st = _search_state(seed, ny, nx, per_iter,
+                       drop=(1, 1) if variant == "none_slice" else None)
+    if variant == "none_slice":
+        assert len(st.locations) < 2 * per_iter
+        assert st._idw[0] == len(st.locations)
+    if variant == "stale":
+        # samples that absorb's kept arrays do not cover
+        taken = set(map(tuple, st.locations))
+        extra = [[y, x] for y in range(0, ny, 7) for x in range(0, nx, 9)
+                 if (y, x) not in taken][:5]
+        st.locations.extend(extra)
+        st.values.extend([0.5] * len(extra))
+        assert st._idw[0] != len(st.locations)
+    got, want = _against_oracle(st, per_iter)
+    assert got.shape == (per_iter, 2)
+    np.testing.assert_array_equal(got, want)
+
+
+@pytest.mark.parametrize("absorb", ["real", "replaced"])
+def test_aua_proposal_reuses_absorbs_idw(monkeypatch, absorb):
+    from repro.apps.anen import workflow as w
+    if absorb == "replaced":
+        def plain_absorb(self, results):
+            # the samples folded in, no IDW arrays kept
+            for slice_locs, r in zip(self._round_slices, results):
+                self.locations.extend(slice_locs)
+                self.values.extend(np.asarray(r).tolist())
+            self.iteration += 1
+        monkeypatch.setattr(w._SearchState, "absorb", plain_absorb)
+    st = _search_state(0, 48, 64, 32)
+    calls = []
+    real = w.idw_interpolate_nearest
+
+    def counted(*a, **kw):
+        calls.append(1)
+        return real(*a, **kw)
+
+    monkeypatch.setattr(w, "idw_interpolate_nearest", counted)
+    at = st.rng.bit_generator.state
+    got = st._adaptive_new(32)
+    assert len(calls) == (0 if absorb == "real" else 1)
+    st.rng.bit_generator.state = at
+    np.testing.assert_array_equal(got, _whole_grid_proposal(st, 32))
+
+
+def test_aua_proposal_span_inside_the_loop_body():
+    """The proposal's record inside the loop body's span is an instant
+    event, so the body's self time still holds the proposal."""
+    from repro import telemetry
+    from repro.apps.anen.workflow import run_adaptive
+    telemetry.enable()
+    telemetry.TRACER.clear()
+    try:
+        r = run_adaptive(seed=2, ny=24, nx=24, n_hist=40, per_iter=20,
+                         max_iters=3, n_tasks=2, slots=2)
+        recs = telemetry.TRACER.snapshot()
+    finally:
+        telemetry.disable()
+        telemetry.TRACER.clear()
+    assert r["all_done"]
+    bodies = [s for s in recs
+              if s["ph"] == "X" and s["name"] == "api.loop.body"]
+    proposals = [s for s in recs if s["name"] == "anen.propose"]
+    assert [b["attrs"]["round"] for b in bodies] == [0, 1, 2]
+    # an instant event, so the proposal's time stays the body's self time
+    assert all(p["ph"] == "i" for p in proposals)
+
+    def inside(s, b):
+        return (s["tid"] == b["tid"] and s["depth"] == b["depth"] + 1
+                and b["ts"] <= s["ts"] <= b["ts"] + b["dur"])
+
+    # round 0 is a random draw; each later round, one proposal
+    assert [sum(inside(p, b) for p in proposals) for b in bodies] == [0, 1, 1]
+    for p in proposals:
+        a = p["attrs"]
+        assert set(a) == {"picks", "explore", "reach", "cells_updated",
+                          "reused_estimate"}
+        assert (a["picks"], a["explore"], a["reused_estimate"]) == (15, 5, 1)
+        assert 0 < a["reach"] and 0 < a["cells_updated"] <= 20 * 24 * 24

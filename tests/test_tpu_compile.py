@@ -63,6 +63,23 @@ def test_anen_distance_kernel_compiles_for_v5e(one_chip):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+def test_idw_with_nearest_distances_keeps_its_topk_on_v5e(one_chip):
+    """The IDW, which also returns each cell's nearest distance, on the NAM
+    grid at a campaign's last round compiles to a fused ``TopK``: the
+    (grid × samples) distance matrix is never sorted or held whole."""
+    from repro.apps.anen.anen import idw_interpolate_nearest
+
+    n = 4 * ANEN_N
+    locs = jax.ShapeDtypeStruct((n, 2), jnp.int32, sharding=one_chip)
+    vals = jax.ShapeDtypeStruct((n,), jnp.float32, sharding=one_chip)
+    compiled = idw_interpolate_nearest.lower(locs, vals, 428, 614).compile()
+    assert 'custom_call_target="TopK"' in compiled.as_text()
+    assert " sort(" not in compiled.as_text()
+    # scratch below a tenth of the float32 (grid × samples) matrix
+    assert (compiled.memory_analysis().temp_size_in_bytes
+            < 428 * 614 * n * 4 // 10)
+
+
 def test_seismic_member_step_compiles_for_v5e(one_chip):
     sources = jax.ShapeDtypeStruct((EVENTS,), jnp.int32, sharding=one_chip)
     compiled = jax.jit(_member_misfits).lower(sources).compile()
