@@ -6,17 +6,22 @@ current velocity model. The scale experiment varies the *concurrency*
 — reproducing the paper's observation that reducing concurrency eliminated
 failures while EnTK's resubmission transparently completed the failed tasks
 (157 attempted for 128 nominal at 2⁵ concurrency in the paper).
+
+The misfit sweep of an inversion iteration (§III-A) is :func:`run_sweep`:
+forward → misfit → Σ over a survey's shots, each shot's recorded data one
+of its member's inputs, the trial model an operand of the fused program.
 """
 
 from __future__ import annotations
 
 import time
-from typing import Dict, List, Optional
+from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
 import numpy as np
 
 from ... import api
+from ... import telemetry as tel
 from ...core import AppManager, Pipeline, Stage, Task, register_executable
 from ...fusion import fusable, fusable_reduction
 from ...rts.base import ResourceDescription
@@ -24,7 +29,10 @@ from ...rts.jax_rts import JaxRTS
 from ...rts.local import LocalRTS
 from .solver import SeismicConfig, forward_simulation, make_velocity_model, misfit
 
-_CACHE: Dict[str, object] = {}
+_CACHE: Dict[object, object] = {}
+
+#: one shot: its source column and its receivers' columns
+Shot = Tuple[int, np.ndarray]
 
 
 def _forward_jit():
@@ -110,7 +118,8 @@ def trial_misfit(synthetic, source_x: int = 0, nx: int = 64, nz: int = 64,
     """Chain link 2: L2 misfit of a trial seismogram against the observed
     data for its source (the observed forward is recomputed from the true
     model, exactly as :func:`eval_misfit` does — the two-link chain's
-    values match the single-kernel sweep to float precision)."""
+    values match the single-kernel sweep to float precision; the Marmousi
+    sweep, :func:`run_sweep`, takes the recorded data as an input)."""
     import jax.numpy as jnp
     cfg = SeismicConfig(nx=nx, nz=nz, nt=nt)
     vel_true = _velocity("true", cfg, seed)
@@ -168,30 +177,9 @@ def run_misfit_chain(n_events: int, slots: int = 4, *, nx: int = 64,
     result carries the runtime that ran the sweep (``rts``)."""
     ens = build_misfit_chain(n_events, nx=nx, nz=nx, nt=nt, seed=seed,
                              dv=dv, fuse=fuse)
-    objective = api.gather(ens, total_misfit, name=f"total-chain-{seed}")
-    holder: Dict[str, JaxRTS] = {}
-
-    def make_rts() -> JaxRTS:
-        holder["rts"] = JaxRTS(slot_oversubscribe=slots, shard=shard)
-        return holder["rts"]
-
-    t0 = time.time()
-    result = api.run(
-        objective, resources=ResourceDescription(slots=slots),
-        rts_factory=make_rts, chain=chain, shard=shard, timeout=timeout)
-    elapsed = time.time() - t0
-    out = {
-        "n_events": n_events,
-        "fused": fuse,
-        "chained": chain,
-        "all_done": result.all_done,
-        "total_misfit": objective.out.result(),
-        "misfits": [float(np.asarray(s.out.result())) for s in ens.specs],
-        "wallclock_s": elapsed,
-        "rts": holder.get("rts"),
-    }
-    result.close()
-    return out
+    return _run_objective(ens, f"total-chain-{seed}", slots=slots,
+                          devices=None, fuse=fuse, chain=chain, shard=shard,
+                          timeout=timeout)
 
 
 @fusable_reduction(kind="sum")
@@ -204,6 +192,125 @@ def total_misfit(values: List) -> float:
     (sharded sweeps reduce via ``psum`` across the mesh), while the scalar
     body keeps running unchanged everywhere fusion is off."""
     return float(np.sum([np.asarray(v) for v in values]))
+
+
+# ---- the misfit sweep: forward → misfit → Σ over a survey's shots -------- #
+
+def _model_operands(*, cfg: SeismicConfig, seed: int) -> Dict[str, object]:
+    """The starting model, handed to the fusion engine as an operand: the
+    sweep's program takes it as an argument, not as a constant of the
+    compiled program (6.9 MB at the Marmousi's widths)."""
+    return {"velocity": _velocity("init", cfg, seed)}
+
+
+@fusable(static_argnames=("cfg", "seed"), operands=_model_operands)
+def shot_synthetic(source_x: int, receiver_x, cfg: SeismicConfig,
+                   seed: int = 0, velocity=None):
+    """Chain link 1 of the sweep: the starting model's seismogram for one
+    shot, gathered at the shot's own receivers. The fused sweep passes the
+    model as ``velocity``; a scalar call looks it up."""
+    if velocity is None:
+        velocity = _velocity("init", cfg, seed)
+    return forward_simulation(velocity, source_x, cfg, receiver_x)
+
+
+register_executable("shot_synthetic", shot_synthetic)
+
+
+@fusable
+def shot_misfit(synthetic, observed):
+    """Chain link 2 of the sweep: the L2 misfit of a shot's synthetic
+    seismogram against its recorded data."""
+    import jax.numpy as jnp
+    return 0.5 * jnp.sum((jnp.asarray(synthetic) - observed) ** 2)
+
+
+register_executable("shot_misfit", shot_misfit)
+
+
+def _records(velocity, sources, receivers, cfg: SeismicConfig):
+    return jax.vmap(lambda s, r: forward_simulation(velocity, s, cfg, r))(
+        sources, receivers)
+
+
+def record_survey(cfg: SeismicConfig, geometry: Sequence[Shot],
+                  seed: int = 0) -> np.ndarray:
+    """The true model's seismograms of the shots, (shots, nt / record_every,
+    n_receivers) float32, in one batched program: the recorded data that a
+    sweep compares against."""
+    import jax.numpy as jnp
+    if "rec" not in _CACHE:
+        _CACHE["rec"] = jax.jit(_records, static_argnames=("cfg",))
+    sources = jnp.asarray([sx for sx, _ in geometry], jnp.int32)
+    receivers = jnp.asarray(np.stack([rx for _, rx in geometry]), jnp.int32)
+    return np.asarray(_CACHE["rec"](_velocity("true", cfg, seed), sources,
+                                    receivers, cfg))
+
+
+def run_sweep(cfg: SeismicConfig, geometry: Sequence[Shot],
+              observed: np.ndarray, *, seed: int = 0, slots: int = 4,
+              devices=None, timeout: float = 3600.0) -> Dict:
+    """The misfit objective of the starting model: Σ over the shots
+    (``geometry``, each a source and its receivers, from
+    :func:`~repro.apps.seismic.solver.shot_geometry`) of each shot's misfit
+    against its recorded data (``observed``, one record per shot), on the
+    JaxRTS data plane. The forward → misfit chain and the Σ run as one
+    fused ``dag`` carrier; ``devices`` pins the runtime's pool. The result
+    carries the runtime that ran the sweep (``rts``)."""
+    observed = np.asarray(observed, np.float32)
+    if observed.shape != (len(geometry), cfg.record_samples,
+                          cfg.n_receivers):
+        raise ValueError(f"recorded data of shape {observed.shape} for "
+                         f"{len(geometry)} shots of {cfg.record_samples} "
+                         f"samples x {cfg.n_receivers} receivers")
+    if tel.enabled():
+        nzp, nxp = cfg.padded_shape
+        tel.event("seismic.sweep", "seismic", shots=len(geometry),
+                  steps=cfg.nt, grid_points=nzp * nxp,
+                  record_samples=cfg.record_samples,
+                  observed_bytes=int(observed.nbytes))
+    forward = api.ensemble(
+        shot_synthetic,
+        over=[{"source_x": int(sx), "receiver_x": np.asarray(rx, np.int32),
+               "cfg": cfg, "seed": seed} for sx, rx in geometry],
+        name=f"sweep-forward-{seed}")
+    misfits = forward.then(shot_misfit,
+                           over=[{"observed": obs} for obs in observed],
+                           name=f"sweep-misfit-{seed}")
+    return _run_objective(misfits, f"sweep-total-{seed}", slots=slots,
+                          devices=devices, fuse=True, chain=True,
+                          shard=True, timeout=timeout)
+
+
+def _run_objective(ens: api.Ensemble, name: str, *, slots: int, devices,
+                   fuse: bool, chain: bool, shard: bool, timeout: float
+                   ) -> Dict:
+    """Σ of the misfit ensemble ``ens`` through ``api.run`` on a JaxRTS."""
+    objective = api.gather(ens, total_misfit, name=name)
+    holder: Dict[str, JaxRTS] = {}
+
+    def make_rts() -> JaxRTS:
+        holder["rts"] = JaxRTS(devices=devices, slot_oversubscribe=slots,
+                               shard=shard)
+        return holder["rts"]
+
+    t0 = time.time()
+    result = api.run(
+        objective, resources=ResourceDescription(slots=slots),
+        rts_factory=make_rts, chain=chain, shard=shard, timeout=timeout)
+    elapsed = time.time() - t0
+    out = {
+        "n_events": len(ens.specs),
+        "fused": fuse,
+        "chained": chain,
+        "all_done": result.all_done,
+        "total_misfit": objective.out.result(),
+        "misfits": [float(np.asarray(s.out.result())) for s in ens.specs],
+        "wallclock_s": elapsed,
+        "rts": holder.get("rts"),
+    }
+    result.close()
+    return out
 
 
 def run_misfit_ensemble(n_events: int, slots: int = 4, *, nx: int = 64,
@@ -219,23 +326,9 @@ def run_misfit_ensemble(n_events: int, slots: int = 4, *, nx: int = 64,
     """
     ens = build_misfit_ensemble(n_events, nx=nx, nz=nx, nt=nt, seed=seed,
                                 dv=dv, fuse=fuse)
-    objective = api.gather(ens, total_misfit, name=f"total-misfit-{seed}")
-    t0 = time.time()
-    result = api.run(
-        objective, resources=ResourceDescription(slots=slots),
-        rts_factory=lambda: JaxRTS(slot_oversubscribe=slots, shard=shard),
-        shard=shard, timeout=timeout)
-    elapsed = time.time() - t0
-    out = {
-        "n_events": n_events,
-        "fused": fuse,
-        "all_done": result.all_done,
-        "total_misfit": objective.out.result(),
-        "misfits": [float(np.asarray(s.out.result())) for s in ens.specs],
-        "wallclock_s": elapsed,
-    }
-    result.close()
-    return out
+    return _run_objective(ens, f"total-misfit-{seed}", slots=slots,
+                          devices=None, fuse=fuse, chain=True, shard=shard,
+                          timeout=timeout)
 
 
 def build_forward_ensemble(n_events: int, *, nx: int = 96, nz: int = 96,

@@ -63,6 +63,102 @@ def test_seismic_ensemble_under_entk():
     assert r["attempts"] >= 4
 
 
+# ---- the survey: moving spreads, decimated records, the CFL check --------- #
+
+# a streamer survey on a 5 m grid: shots 25 m (5 cells) apart, three
+# receivers 25, 50 and 75 m behind the shot
+SURVEY = SeismicConfig(nx=160, nz=40, nt=60, dx=5.0, dt=1e-3, c_max=2000.0,
+                       n_receivers=3, first_shot_m=400.0,
+                       shot_spacing_m=25.0,
+                       receiver_offsets_m=(-25.0, -50.0, -75.0))
+
+
+def _layered(cfg):
+    """Laterally uniform: 1800 m/s over 2000 m/s."""
+    z = np.arange(cfg.nz)[:, None] < 10
+    return jnp.asarray(np.where(z, 1800.0, 2000.0)
+                       * np.ones((1, cfg.nx)), jnp.float32)
+
+
+def test_receivers_move_with_the_source():
+    from repro.apps.seismic.solver import shot_geometry
+
+    s0, r0 = shot_geometry(SURVEY, 3)
+    s1, r1 = shot_geometry(SURVEY, 4)
+    assert s1 - s0 == 5 and np.array_equal(r1[1:], r0[:-1])
+    assert np.array_equal(r1 - r0, [5, 5, 5])
+    # on a laterally uniform model, a shot 25 m over records the same
+    # traces: the spread moved with it (the wave reaches no damped cell)
+    vel = _layered(SURVEY)
+    seis0 = forward_simulation(vel, s0, SURVEY, receiver_x=r0)
+    seis1 = forward_simulation(vel, s1, SURVEY, receiver_x=r1)
+    assert float(jnp.abs(seis0).max()) > 0
+    assert np.array_equal(np.asarray(seis0), np.asarray(seis1))
+    fixed = forward_simulation(vel, s1, SURVEY, receiver_x=r0)
+    assert not np.array_equal(np.asarray(seis1), np.asarray(fixed))
+
+
+def test_receivers_move_with_the_source_in_marmousi_geometry():
+    """At the Marmousi's 4 m grid the shots' 25 m are 6.25 cells: the
+    source moves 6 or 7 columns, and receiver i of a shot sits where
+    receiver i − 1 of the shot before sat."""
+    from repro.apps.seismic.solver import shot_geometry
+
+    cfg = SeismicConfig(nx=2301, nz=751, nt=5800, dx=4.0, dt=5e-4,
+                        n_receivers=96, record_every=8,
+                        first_shot_m=3000.0, shot_spacing_m=25.0,
+                        receiver_offsets_m=tuple(-200.0 - 25.0 * i
+                                                 for i in range(96)))
+    steps = set()
+    for shot in range(239):
+        s0, r0 = shot_geometry(cfg, shot)
+        s1, r1 = shot_geometry(cfg, shot + 1)
+        assert np.array_equal(r1[1:], r0[:-1])
+        steps.add(s1 - s0)
+    assert steps == {6, 7}
+    assert shot_geometry(cfg, 0)[1].min() == 106       # 425 m
+    assert shot_geometry(cfg, 239)[0] == 2244          # 8975 m
+
+
+def test_decimated_record_is_every_kth_sample_of_the_full_one():
+    import dataclasses
+    from repro.apps.seismic.solver import shot_geometry
+
+    decimated = dataclasses.replace(SURVEY, record_every=4)
+    source, spread = shot_geometry(SURVEY, 2)
+    vel = _layered(SURVEY)
+    full = forward_simulation(vel, source, SURVEY, receiver_x=spread)
+    every = forward_simulation(vel, source, decimated, receiver_x=spread)
+    assert every.shape == (SURVEY.nt // 4, 3)
+    assert np.array_equal(np.asarray(every), np.asarray(full)[::4])
+
+
+def test_cfl_check_raises_above_the_leapfrog_limit():
+    # 5500 m/s on a 4 m grid: dt ≤ 4 / (5500 √2) = 0.514 ms
+    SeismicConfig(dx=4.0, dt=5e-4, c_max=5500.0)
+    with pytest.raises(ValueError, match="CFL"):
+        SeismicConfig(dx=4.0, dt=6e-4, c_max=5500.0)
+    with pytest.raises(ValueError, match="record_every"):
+        SeismicConfig(dx=4.0, dt=5e-4, nt=5801, record_every=8)
+
+
+def test_marmousi_like_models_from_the_seed():
+    cfg = SeismicConfig(nx=192, nz=64, dx=12.0, dt=1.5e-3, model="marmousi")
+    true = np.asarray(make_velocity_model(cfg, "true", seed=5))
+    start = np.asarray(make_velocity_model(cfg, "init", seed=5))
+    assert true.min() == 1500.0 and true.max() == 5500.0
+    assert np.all(true[:3] == 1500.0) and np.all(start[:3] == 1500.0)
+    # the smoothing keeps the values inside the true model's range and
+    # takes the sharp contrasts out
+    assert 1500.0 <= start.min() and start.max() < 5500.0
+    assert (np.abs(np.diff(start[3:], axis=0)).max()
+            < np.abs(np.diff(true[3:], axis=0)).max() / 5)
+    assert np.array_equal(
+        true, np.asarray(make_velocity_model(cfg, "true", seed=5)))
+    assert not np.array_equal(
+        true, np.asarray(make_velocity_model(cfg, "true", seed=6)))
+
+
 # --------------------------------------------------------------------------- #
 # AnEn
 # --------------------------------------------------------------------------- #
