@@ -40,9 +40,9 @@ ROOT = Path(__file__).resolve().parent
 # The AnEn campaign (paper §III-B) on the NCEP NAM 12 km CONUS grid 218
 # (428 x 614 points), a year of daily forecast/observation pairs, the
 # generator's 3 predictor variables and k = 12 analogs; 64 members per round
-# over 1024 new locations, 4 rounds. The IDW estimate over all locations
-# (grid x 4096 x 4 B = 4.3 GB in the last round) and the 1.5 GB history fit
-# one v5e's 16 GB.
+# over 1024 new locations, 4 rounds. The IDW, which scores each round's 1024
+# new locations over the grid and keeps every cell's 8 nearest (16.8 MB),
+# and the 1.5 GB history fit one v5e's 16 GB.
 ANEN = dict(ny=428, nx=614, n_hist=365, n_tasks=64, per_iter=1024,
             max_iters=4)
 # The seismic misfit sweep (paper §III-A): the 128 events of Fig. 10 on a

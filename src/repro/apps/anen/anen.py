@@ -116,6 +116,35 @@ def compute_analogs(data: AnEnData, locations: jnp.ndarray, k: int
     return picked.mean(axis=1)                      # (n,)
 
 
+def _grid_nearest(locations: jnp.ndarray, values: jnp.ndarray, ny: int,
+                  nx: int, k: int) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """Each grid cell's ``k`` nearest samples (fewer if there are fewer
+    samples), nearest first and the lower sample index first on a tie, as
+    ``lax.top_k`` orders them: their squared distances and values, both
+    (ny·nx, k). The distances are integers below 2²⁴, so exact in float32."""
+    yy, xx = jnp.mgrid[0:ny, 0:nx]
+    gy = yy.reshape(-1).astype(jnp.float32)
+    gx = xx.reshape(-1).astype(jnp.float32)
+    ly = locations[:, 0].astype(jnp.float32)
+    lx = locations[:, 1].astype(jnp.float32)
+    d2 = ((gy[:, None] - ly[None]) ** 2
+          + (gx[:, None] - lx[None]) ** 2)          # (G, n)
+    neg_d2, idx = jax.lax.top_k(-d2, min(k, d2.shape[1]))
+    return -neg_d2, values[idx]
+
+
+def _weighted(d2: jnp.ndarray, vals: jnp.ndarray, ny: int, nx: int,
+              power: float, eps: float) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """The inverse-distance estimate over each cell's nearest samples, and
+    its squared distance to the nearest, both (ny, nx). A +inf distance
+    weighs exactly 0."""
+    w = 1.0 / (d2 ** (power / 2) + eps)
+    est = (w * vals).sum(axis=1) / w.sum(axis=1)
+    # the top-1 as a min over the k columns: a slice of its own would keep
+    # XLA from rewriting the sort under ``top_k`` into a partial TopK
+    return est.reshape(ny, nx), d2.min(axis=1).reshape(ny, nx)
+
+
 @functools.partial(jax.jit, static_argnames=("ny", "nx", "power",
                                              "k_nearest", "eps"))
 def idw_interpolate_nearest(locations: jnp.ndarray, values: jnp.ndarray,
@@ -130,24 +159,48 @@ def idw_interpolate_nearest(locations: jnp.ndarray, values: jnp.ndarray,
     refinement effective: far-away samples cannot wash out a freshly
     refined front. Jitted so the (grid × samples) distance matrix is built
     once inside one program: op by op, its temporaries would hold three
-    copies of it at once, over 12 GB on the NAM grid at 4096 samples. The
-    distances are integers below 2²⁴, so exact in float32.
+    copies of it at once, over 12 GB on the NAM grid at 4096 samples.
     """
-    yy, xx = jnp.mgrid[0:ny, 0:nx]
-    gy = yy.reshape(-1).astype(jnp.float32)
-    gx = xx.reshape(-1).astype(jnp.float32)
-    ly = locations[:, 0].astype(jnp.float32)
-    lx = locations[:, 1].astype(jnp.float32)
-    d2 = ((gy[:, None] - ly[None]) ** 2
-          + (gx[:, None] - lx[None]) ** 2)          # (G, n)
-    k = min(k_nearest, d2.shape[1])
-    neg_d2, idx = jax.lax.top_k(-d2, k)             # (G, k) nearest
-    w = 1.0 / ((-neg_d2) ** (power / 2) + eps)
-    vals = values[idx]                               # (G, k)
-    est = (w * vals).sum(axis=1) / w.sum(axis=1)
-    # the top-1 as a max over the k columns: a slice of its own would keep
-    # XLA from rewriting the sort under ``top_k`` into a partial TopK
-    return est.reshape(ny, nx), (-neg_d2.max(axis=1)).reshape(ny, nx)
+    return _weighted(*_grid_nearest(locations, values, ny, nx, k_nearest),
+                     ny, nx, power, eps)
+
+
+def idw_empty(ny: int, nx: int, k_nearest: int = 8
+              ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """The state of :func:`idw_interpolate_merge` before any sample: every
+    cell's k nearest at +inf distance with value 0."""
+    return (jnp.full((ny * nx, k_nearest), jnp.inf, jnp.float32),
+            jnp.zeros((ny * nx, k_nearest), jnp.float32))
+
+
+@functools.partial(jax.jit, static_argnames=("ny", "nx", "power", "eps"),
+                   donate_argnames=("kept",))
+def idw_interpolate_merge(kept: Tuple[jnp.ndarray, jnp.ndarray],
+                          locations: jnp.ndarray, values: jnp.ndarray,
+                          ny: int, nx: int, power: float = 2.0,
+                          eps: float = 1e-6):
+    """:func:`idw_interpolate_nearest` made incremental: fold new samples
+    into ``kept``, each cell's k nearest earlier samples (squared distances
+    and values, both (ny·nx, k), nearest first; :func:`idw_empty` before
+    the first), and return the estimate, the nearest distances and the new
+    state, whose buffers replace ``kept``'s.
+
+    The k nearest of (earlier ∪ new) are the k nearest of (kept ∪ the new
+    samples' k nearest), so the grid is scored against the new samples
+    alone. A stable sort of those 2k candidates, kept first, settles a tie
+    as ``top_k`` over every sample would: earlier samples have the lower
+    indices, and each half is already in order. Same k nearest in the same
+    order, so the same estimate as the whole-grid program over all the
+    samples.
+    """
+    k = kept[0].shape[1]
+    d2, vals = _grid_nearest(locations, values, ny, nx, k)
+    d2 = jnp.concatenate([kept[0], d2], axis=1)
+    vals = jnp.concatenate([kept[1], vals], axis=1)
+    d2, vals = jax.lax.sort((d2, vals), dimension=1, is_stable=True,
+                            num_keys=1)
+    d2, vals = d2[:, :k], vals[:, :k]
+    return (*_weighted(d2, vals, ny, nx, power, eps), (d2, vals))
 
 
 def idw_interpolate(locations: jnp.ndarray, values: jnp.ndarray,

@@ -31,7 +31,8 @@ from ...fusion import fusable, fusable_reduction
 from ...rts.base import ResourceDescription
 from ...rts.jax_rts import JaxRTS
 from .anen import (AnEnConfig, compute_analogs, gradient_magnitude,
-                   idw_interpolate_nearest, make_dataset, rmse)
+                   idw_empty, idw_interpolate_merge, idw_interpolate_nearest,
+                   make_dataset, rmse)
 
 _DATASETS: Dict[int, object] = {}
 
@@ -201,6 +202,9 @@ class _SearchState:
         # the estimate, each cell's squared distance to its nearest sample),
         # device arrays the AUA proposal reads back instead of redoing them
         self._idw = None
+        # each cell's 8 nearest of those samples (squared distances and
+        # values, device arrays), into which absorb merges the next round
+        self._kept = None
 
     # ---- location proposal ------------------------------------------------ #
 
@@ -307,15 +311,37 @@ class _SearchState:
         ``results`` line up with the round's location slices by member
         index; each value may be a list, ndarray, or a device-resident
         :class:`~repro.fusion.ArrayResult` — ``np.asarray`` reads them all.
+
+        The IDW merges the round's new samples into each cell's kept 8
+        nearest when those cover exactly the samples before this round;
+        otherwise (the first round, or samples added behind absorb's back)
+        it rebuilds them from every sample.
         """
+        before = len(self.locations)
         for slice_locs, r in zip(self._round_slices, results):
             if r is None:
                 continue
             self.locations.extend(slice_locs)
             self.values.extend(np.asarray(r).tolist())
-        est, near = self._interpolate()
-        self._idw = (len(self.locations), est, near)
-        self.errors.append(rmse(est, self.data.truth))
+        merged = self._kept is not None and self._idw[0] == before
+        first = before if merged else 0
+        if first < len(self.locations):
+            import jax.numpy as jnp
+            ny, nx = self.cfg.ny, self.cfg.nx
+            kept = self._kept if merged else idw_empty(ny, nx)
+            self._kept = None       # the merge takes over its buffers
+            locs = jnp.asarray(self.locations[first:],
+                               jnp.int32).reshape(-1, 2)
+            vals = jnp.asarray(self.values[first:], jnp.float32)
+            est, near, self._kept = idw_interpolate_merge(kept, locs, vals,
+                                                          ny, nx)
+            self._idw = (len(self.locations), est, near)
+        if tel.enabled():
+            tel.event("anen.absorb", "anen",
+                      new_samples=len(self.locations) - before,
+                      merged=int(merged),
+                      columns=len(self.locations) - first)
+        self.errors.append(rmse(self._idw[1], self.data.truth))
         self.iteration += 1
 
     # ---- declarative description ------------------------------------------- #

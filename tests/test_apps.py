@@ -247,21 +247,42 @@ def _whole_grid_proposal(st, n):
     return np.concatenate([explore, np.asarray(picks, np.int32)], axis=0)
 
 
+def _new_state(seed, ny, nx, per_iter, rounds=2, n_tasks=3, method="aua"):
+    from repro.apps.anen.workflow import _SearchState
+    return _SearchState(method, seed, AnEnConfig(ny=ny, nx=nx, n_hist=12,
+                                                 seed=seed),
+                        per_iter, max_iters=rounds + 1, n_tasks=n_tasks)
+
+
+def _absorb_round(st, per_iter, n_tasks=3, drop=None):
+    """One round placed by the state's own proposal, valued from the truth
+    field and absorbed; the result of slice ``drop`` comes back None."""
+    truth = np.asarray(st.data.truth)
+    slices = [sl for sl in np.array_split(st.propose(per_iter), n_tasks)
+              if len(sl)]
+    st._round_slices = [sl.tolist() for sl in slices]
+    st.absorb([None if i == drop else truth[sl[:, 0], sl[:, 1]]
+               for i, sl in enumerate(slices)])
+
+
+def _add_behind_absorb(st, n=5):
+    """Samples that absorb's kept arrays do not cover."""
+    ny, nx = st.cfg.ny, st.cfg.nx
+    taken = set(map(tuple, st.locations))
+    extra = [[y, x] for y in range(0, ny, 7) for x in range(0, nx, 9)
+             if (y, x) not in taken][:n]
+    st.locations.extend(extra)
+    st.values.extend([0.5] * len(extra))
+
+
 def _search_state(seed, ny, nx, per_iter, rounds=2, n_tasks=3, drop=None):
     """An AUA search state after ``rounds`` absorbed rounds, each placed by
     the state's own proposal and valued from the truth field; the result of
     slice ``drop = (round, slice)`` comes back None."""
-    from repro.apps.anen.workflow import _SearchState
-    st = _SearchState("aua", seed, AnEnConfig(ny=ny, nx=nx, n_hist=12,
-                                              seed=seed),
-                      per_iter, max_iters=rounds + 1, n_tasks=n_tasks)
-    truth = np.asarray(st.data.truth)
+    st = _new_state(seed, ny, nx, per_iter, rounds, n_tasks)
     for r in range(rounds):
-        slices = [sl for sl in np.array_split(st.propose(per_iter), n_tasks)
-                  if len(sl)]
-        st._round_slices = [sl.tolist() for sl in slices]
-        st.absorb([None if (r, i) == drop else truth[sl[:, 0], sl[:, 1]]
-                   for i, sl in enumerate(slices)])
+        _absorb_round(st, per_iter, n_tasks,
+                      drop=drop[1] if drop and drop[0] == r else None)
     return st
 
 
@@ -295,16 +316,80 @@ def test_aua_proposal_matches_whole_grid_greedy(monkeypatch, seed, ny, nx,
         assert len(st.locations) < 2 * per_iter
         assert st._idw[0] == len(st.locations)
     if variant == "stale":
-        # samples that absorb's kept arrays do not cover
-        taken = set(map(tuple, st.locations))
-        extra = [[y, x] for y in range(0, ny, 7) for x in range(0, nx, 9)
-                 if (y, x) not in taken][:5]
-        st.locations.extend(extra)
-        st.values.extend([0.5] * len(extra))
+        _add_behind_absorb(st)
         assert st._idw[0] != len(st.locations)
     got, want = _against_oracle(st, per_iter)
     assert got.shape == (per_iter, 2)
     np.testing.assert_array_equal(got, want)
+
+
+# ---- absorb's incremental IDW against the whole-grid program -------------- #
+
+
+@pytest.mark.parametrize("method,seed,ny,nx,per_iter,variant", [
+    ("aua", 0, 8, 8, 6, "ties"), ("random", 1, 8, 8, 6, "ties"),
+    ("aua", 1, 24, 24, 20, "none_slice"),
+    ("random", 2, 24, 24, 20, "none_slice"),
+    ("aua", 2, 24, 24, 5, "first_under_8"),
+    ("random", 0, 24, 24, 5, "first_under_8"),
+    ("aua", 0, 48, 64, 64, "stale"), ("random", 1, 48, 64, 64, "stale"),
+])
+def test_absorb_merges_to_the_whole_grid_idw(method, seed, ny, nx, per_iter,
+                                             variant):
+    """After every round, the estimate and nearest distances that absorb
+    merged round by round are those of the whole-grid IDW over every
+    sample absorbed: the nearest distances exactly, the estimate to
+    float32 rounding of the same sum."""
+    from repro.apps.anen.anen import idw_interpolate_nearest
+    st = _new_state(seed, ny, nx, per_iter, rounds=4, method=method)
+    for r in range(4):
+        if variant == "stale" and r == 2:
+            _add_behind_absorb(st)
+        before = len(st.locations)
+        _absorb_round(st, per_iter,
+                      drop=1 if variant == "none_slice" and r == 1 else None)
+        assert st._idw[0] == len(st.locations) > before
+        if variant == "first_under_8" and r == 0:
+            assert len(st.locations) < 8
+        est, near = idw_interpolate_nearest(
+            jnp.asarray(st.locations, jnp.int32),
+            jnp.asarray(st.values, jnp.float32), ny, nx)
+        np.testing.assert_array_equal(np.asarray(st._idw[2]),
+                                      np.asarray(near))
+        np.testing.assert_allclose(np.asarray(st._idw[1]), np.asarray(est),
+                                   rtol=1e-6, atol=0)
+    if variant == "none_slice":
+        assert len(st.locations) < 4 * per_iter
+
+
+@pytest.mark.parametrize("method", ["aua", "random"])
+def test_absorb_reports_merge_or_rebuild(method):
+    """``anen.absorb`` says, each round, whether the IDW merged the new
+    samples into the kept nearest (scoring only them over the grid) or
+    rebuilt from every sample: round 1, and after samples were added
+    behind absorb's back."""
+    from repro import telemetry
+    telemetry.enable()
+    telemetry.TRACER.clear()
+    try:
+        st = _new_state(3, 24, 24, 20, rounds=4, method=method)
+        totals = []
+        for r in range(4):
+            if r == 2:
+                _add_behind_absorb(st)
+            _absorb_round(st, 20)
+            totals.append(len(st.locations))
+        recs = telemetry.TRACER.snapshot()
+    finally:
+        telemetry.disable()
+        telemetry.TRACER.clear()
+    events = [e for e in recs if e["name"] == "anen.absorb"]
+    assert all(e["ph"] == "i" for e in events)
+    attrs = [e["attrs"] for e in events]
+    assert [a["merged"] for a in attrs] == [0, 1, 0, 1]
+    assert [a["new_samples"] for a in attrs] == [20, 20, 20, 20]
+    # a merge scores the round's new samples; a rebuild, every sample
+    assert [a["columns"] for a in attrs] == [20, 20, totals[2], 20]
 
 
 @pytest.mark.parametrize("absorb", ["real", "replaced"])

@@ -80,6 +80,25 @@ def test_idw_with_nearest_distances_keeps_its_topk_on_v5e(one_chip):
             < 428 * 614 * n * 4 // 10)
 
 
+def test_idw_merge_keeps_its_topk_and_reuses_the_kept_state_on_v5e(
+        one_chip):
+    """absorb's incremental IDW on the NAM grid: one round's samples scored
+    through a fused ``TopK``, and the kept 8 nearest of every cell written
+    back into the buffers they came in."""
+    from repro.apps.anen.anen import idw_interpolate_merge
+
+    g, k = 428 * 614, 8
+    kept = tuple(jax.ShapeDtypeStruct((g, k), jnp.float32, sharding=one_chip)
+                 for _ in range(2))
+    locs = jax.ShapeDtypeStruct((ANEN_N, 2), jnp.int32, sharding=one_chip)
+    vals = jax.ShapeDtypeStruct((ANEN_N,), jnp.float32, sharding=one_chip)
+    compiled = idw_interpolate_merge.lower(kept, locs, vals, 428,
+                                           614).compile()
+    assert 'custom_call_target="TopK"' in compiled.as_text()
+    # both kept arrays, the grid axis padded to the chip's tiles
+    assert compiled.memory_analysis().alias_size_in_bytes >= 2 * g * k * 4
+
+
 def test_seismic_member_step_compiles_for_v5e(one_chip):
     sources = jax.ShapeDtypeStruct((EVENTS,), jnp.int32, sharding=one_chip)
     compiled = jax.jit(_member_misfits).lower(sources).compile()
