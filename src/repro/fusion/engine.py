@@ -10,7 +10,7 @@ Given a micro-batch of member tasks (same kernel, congruent kwargs — see
    variable-length arguments to the group maximum by edge replication,
    which is safe for per-row kernels because padded rows are trimmed from
    the outputs before delivery,
-3. dispatches **once per link**: the kernel's hand-written batched
+3. dispatches **once per composed run of nodes**: the kernel's hand-written batched
    implementation when it has one, else ``jax.vmap`` of the scalar kernel,
    jitted with a cache keyed on (kernel, static arguments) so repeated
    micro-batches of one ensemble reuse the trace,
@@ -19,25 +19,28 @@ Given a micro-batch of member tasks (same kernel, congruent kwargs — see
    budgets and resume semantics are per-task, exactly as if the members
    had run scalar.
 
-Chain fusion (PR 5) extends this across stages: :class:`ChainExecution`
-takes a *list* of links (one micro-batch of members through L elementwise
-stages), composes consecutive ``vmap``-able links into a single jitted
-program (``jit(vmap(g∘f))``) and carries the stacked intermediate outputs
-between links device-resident — the host never re-stacks, and the control
-plane never sits between stages. Execution is **asynchronous**: the
-carrier's worker thread only resolves inputs, stacks and enqueues the
-dispatches (:meth:`ChainExecution.dispatch`), streaming per-link records to
-a completion drainer which blocks on the device output, fans out the
-per-stage per-member completions in link order (:meth:`ChainExecution.drain`)
-and releases the device lease. Host-side stacking of micro-batch *n+1*
-therefore overlaps device compute of micro-batch *n*.
+Every fused carrier — a fused group, a chain of elementwise stages, a DAG
+with fan-in reductions and broadcasts, each optionally sharded — runs in
+one executor, :class:`CarrierExecution`. It takes a *list* of nodes (a
+fused group is one node; a chain cohort one node per link), composes
+consecutive traceable nodes into a single jitted program
+(``jit(vmap(g∘f))``) and carries the stacked intermediate outputs between
+nodes device-resident — the host never re-stacks, and the control plane
+never sits between stages. Execution is **asynchronous**: the carrier's
+worker thread only resolves inputs, stacks and enqueues the dispatches
+(:meth:`CarrierExecution.dispatch`), streaming per-node records to a
+completion drainer which blocks on the device output, fans out the
+per-stage per-member completions in node order
+(:meth:`CarrierExecution.drain`) and releases the device lease. Host-side
+stacking of micro-batch *n+1* therefore overlaps device compute of
+micro-batch *n*.
 
-SPMD sharding (PR 6) widens one carrier across the whole device mesh: when
-the RTS leases several distinct devices for a carrier (``mesh_devices``),
-the stacked member kwargs are placed with ``NamedSharding`` over a 1-D
+SPMD sharding widens one carrier across the whole device mesh: when the
+RTS leases several distinct devices for a carrier (``mesh_devices``), the
+stacked member kwargs are placed with ``NamedSharding`` over a 1-D
 ``Mesh`` on the member axis and the composed program (or hand-batched
 kernel) executes under ``shard_map`` — ONE XLA program spans every leased
-device, chain intermediates stay sharded end-to-end between links, and the
+device, intermediates stay sharded end-to-end between nodes, and the
 fan-out hands members sharding-aware lazy slices (a per-member read touches
 one device's shard, never a batch gather). Every sharded wrapper passes
 ``check_vma=False``: user kernels may contain ``pallas_call``, which has no
@@ -45,14 +48,14 @@ replication rule. Any sharded-dispatch failure degrades through the
 existing ladder (per-stage fused on one device, then per-member scalar).
 
 Failure isolation: a member whose outputs contain non-finite values at
-link *k* FAILS at *k* and its downstream links fail with an upstream
+node *k* FAILS at *k* and its downstream nodes fail with an upstream
 marker, while every other member completes; an exception raised by a
-chain/batched dispatch degrades the remaining links to per-stage fused
-execution (consuming the already-resolved upstream values), and a failing
-per-stage dispatch degrades further to per-member scalar execution so only
-the actually-culpable members fail. Resume of a partially-failed batch
-therefore re-runs exactly the failed members, re-entering mid-chain from
-the last journaled link.
+composed/batched dispatch degrades the remaining nodes to per-stage fused
+execution (:func:`execute_fused`, consuming the already-resolved upstream
+values), and a failing per-stage dispatch degrades further to per-member
+scalar execution so only the actually-culpable members fail. Resume of a
+partially-failed batch therefore re-runs exactly the failed members,
+re-entering mid-chain from the last journaled link.
 """
 
 from __future__ import annotations
@@ -69,7 +72,8 @@ import numpy as np
 from .. import telemetry as tel
 from ..core.pst import Task, resolve_executable
 from ..rts.base import TaskCompletion
-from .groups import FusionSpec, fusion_spec, parse_dag_tag, reduction_spec
+from .groups import (FusionSpec, fusion_spec, parse_chain_tag, parse_dag_tag,
+                     reduction_spec)
 from .handles import ArrayResult, LazySlice
 
 Deliver = Callable[[TaskCompletion], None]
@@ -384,42 +388,6 @@ def _prepare(calls: Sequence[Tuple[Callable, list, dict]],
     return fn0, spec, static_kw, shared_kw, stacked, valid_lens, target_b
 
 
-def _continuation_calls(tasks: Sequence[Task], prev_tasks: Sequence[Task]
-                        ) -> Tuple[List[Tuple[Callable, list, dict]], str]:
-    """Resolve a continuation link's members WITHOUT touching their carried
-    input: the carry arrives device-resident from the previous link, so its
-    placeholder must not hit the result store (mid-chain it has no value
-    there yet — that is the whole point). Returns the carry kwarg name."""
-    from ..api.runtime import FUTURE_KEY
-    from ..api.runtime import resolve as resolve_placeholders
-
-    calls: List[Tuple[Callable, list, dict]] = []
-    names: set = set()
-    for t, prev in zip(tasks, prev_tasks):
-        if t.executable != TRAMPOLINE:
-            raise Incongruent("chain link is not a data-flow task")
-        if t.kwargs.get("__args__"):
-            raise Incongruent("chain link has positional args")
-        ns = t.kwargs["__ns__"]
-        fn = resolve_executable(t.kwargs["__fn__"])
-        carry_k = None
-        other: Dict[str, Any] = {}
-        for k, v in (t.kwargs.get("__kwargs__") or {}).items():
-            if isinstance(v, dict) and set(v) == {FUTURE_KEY}:
-                if v[FUTURE_KEY] == prev.name and carry_k is None:
-                    carry_k = k
-                    continue
-                raise Incongruent("chain link consumes a non-chain future")
-            other[k] = _unwrap(resolve_placeholders(v, ns))
-        if carry_k is None:
-            raise Incongruent("chain link does not consume its predecessor")
-        calls.append((fn, [], other))
-        names.add(carry_k)
-    if len(names) != 1:
-        raise Incongruent("chain links disagree on the carry kwarg")
-    return calls, names.pop()
-
-
 # --------------------------------------------------------------------------- #
 # Dispatch
 # --------------------------------------------------------------------------- #
@@ -473,13 +441,13 @@ def _dispatch(fn, spec: FusionSpec, static_kw: dict, shared_kw: dict,
 
 
 class _LinkPlan:
-    """One prepared chain link: resolved kernel + stacked batch kwargs."""
+    """One prepared ensemble node: resolved kernel + stacked batch kwargs."""
 
     __slots__ = ("tasks", "fn", "spec", "static_kw", "shared_kw", "stacked",
-                 "valid_lens", "carry_name", "statics_key", "t_dispatch")
+                 "valid_lens", "statics_key", "t_dispatch")
 
     def __init__(self, tasks, fn, spec, static_kw, shared_kw, stacked,
-                 valid_lens, carry_name) -> None:
+                 valid_lens) -> None:
         self.tasks = tasks
         self.fn = fn
         self.spec = spec
@@ -487,7 +455,6 @@ class _LinkPlan:
         self.shared_kw = shared_kw
         self.stacked = stacked
         self.valid_lens = valid_lens
-        self.carry_name = carry_name
         self.statics_key = _statics_key(static_kw)
         self.t_dispatch: Optional[float] = None
 
@@ -539,59 +506,6 @@ def shard_pad(n_members: int, n_shards: int) -> int:
     if per > 512:
         return n_shards * (256 * math.ceil(per / 256))
     return n_shards * (1 << max(0, per - 1).bit_length())
-
-
-def _composed_segment(plans: Sequence[_LinkPlan], mesh=None) -> Callable:
-    """One jitted program running consecutive vmap-able links back to back —
-    literally ``jit(vmap(g∘f))`` for a 2-link segment. The carried
-    intermediate is an XLA value inside the program: it never materializes
-    on the host, and XLA is free to fuse across the link boundary. Every
-    link's output is still returned (the fan-out owes each stage its
-    per-member completions).
-
-    With ``mesh``, the whole segment runs under ``shard_map`` on the member
-    axis: one program spans every mesh device and the carried intermediates
-    stay sharded across link boundaries."""
-    import jax
-
-    metas = [(p.fn, dict(p.static_kw), p.carry_name) for p in plans]
-
-    def seg(stacked_list, shared_list, carry):
-        outs = []
-        for (fn, static_kw, carry_name), kwb, shb in zip(
-                metas, stacked_list, shared_list):
-            kw = dict(kwb)
-            if carry_name is not None:
-                kw[carry_name] = carry
-            def call(kw_, sh_, fn=fn, static_kw=static_kw):
-                return fn(**kw_, **sh_, **static_kw)
-            out = jax.vmap(call, in_axes=(0, None))(kw, shb)
-            outs.append(out)
-            carry = out
-        return outs
-
-    cache_key: Optional[Tuple] = tuple(
-        (p.fn, p.statics_key, tuple(sorted(p.stacked)), p.carry_name,
-         tuple(sorted(p.shared_kw))) for p in plans)
-    if any(p.statics_key is None for p in plans):
-        cache_key = None
-
-    if mesh is None:
-        return _jit_cached(("chain", cache_key) if cache_key else None,
-                           lambda: jax.jit(seg))
-
-    from jax.sharding import PartitionSpec as P
-
-    def build():
-        # check_vma=False: links may contain pallas_call (no replication
-        # rule); out_specs is a pytree prefix over every link's output
-        return jax.jit(jax.shard_map(
-            seg, mesh=mesh, in_specs=(P("m"), P(), P("m")),
-            out_specs=P("m"), check_vma=False))
-
-    return _jit_cached(
-        ("chain-shard", _mesh_key(mesh), cache_key) if cache_key else None,
-        build)
 
 
 # --------------------------------------------------------------------------- #
@@ -675,7 +589,7 @@ class _FanOut:
 
 
 # --------------------------------------------------------------------------- #
-# Single-link entry point (also the chain's per-stage degrade unit)
+# Per-stage fused dispatch (the carrier degrade ladder's per-stage rung)
 # --------------------------------------------------------------------------- #
 
 def execute_fused(
@@ -690,11 +604,16 @@ def execute_fused(
 ) -> Dict[str, int]:
     """Run ``members`` as one fused dispatch; deliver one completion each.
 
+    This is the per-stage rung of :class:`CarrierExecution`'s degrade
+    ladder, not an executor of its own: it runs one stage of a carrier
+    that could not compose, and falls to per-member scalar execution
+    itself when its own dispatch fails.
+
     Returns execution statistics (``fused`` / ``scalar_fallback`` /
     ``failed`` member counts). ``canceled`` uids are skipped without a
     completion (the same semantics as dropping a queued task on cancel);
     ``fault_injector`` is honoured per member so the failure experiments
-    behave identically on the fused path. ``overrides`` (chain degrade)
+    behave identically on the fused path. ``overrides`` (carrier degrade)
     resolves future placeholders from the carrier's already-computed
     upstream values instead of the store.
     """
@@ -800,447 +719,10 @@ def _scalar_fallback(live: Sequence[Task], cancel_event: threading.Event,
     return stats
 
 
-# --------------------------------------------------------------------------- #
-# Chain execution (async: dispatch on the carrier worker, drain elsewhere)
-# --------------------------------------------------------------------------- #
-
-class ChainExecution:
-    """One micro-batch of members through L chain links, asynchronously.
-
-    The carrier worker calls :meth:`dispatch` — resolve, stack, enqueue the
-    composed device dispatches, streaming one record per link — and
-    returns. The RTS's completion drainer calls :meth:`drain`, which blocks
-    on each link's device output in order and fans out the per-stage,
-    per-member completions (the journal sees exactly the records a
-    per-stage run would have produced, in the same order). A single-link
-    "chain" is the plain PR-4 fused micro-batch, just asynchronous.
-
-    Degrade ladder: a failed chain dispatch at link *k* falls back to
-    per-stage fused execution of links *k..L-1* (consuming the carrier's
-    own upstream values — never the store, which mid-chain may not have
-    been routed yet), and a failed per-stage dispatch falls back to
-    per-member scalar execution (inside :func:`execute_fused`). A sharded
-    carrier (``mesh_devices``) enters the same ladder: any failure in the
-    SPMD dispatch streams a degrade record and links re-run per-stage
-    fused on a single device.
-    """
-
-    def __init__(self, links: Sequence[Sequence[Task]],
-                 devices: Sequence[Any],
-                 cancel_event: threading.Event,
-                 deliver: Deliver,
-                 *,
-                 canceled: Optional[set] = None,
-                 fault_injector: Optional[Callable[[Task], bool]] = None,
-                 compose: bool = True,
-                 mesh_devices: Optional[Sequence[Any]] = None) -> None:
-        self.links: List[List[Task]] = [list(link) for link in links]
-        self.compose = compose
-        self.devices = devices
-        self.cancel_event = cancel_event
-        self.deliver = deliver
-        self.canceled = canceled if canceled is not None else set()
-        self.fault_injector = fault_injector
-        self.started = time.time()
-        self._mesh = build_mesh(mesh_devices)
-        self.tier = ("shard" if self._mesh is not None
-                     else "chain" if len(self.links) > 1 else "fused")
-        self.stats = {"fused": 0, "scalar_fallback": 0, "failed": 0,
-                      "dispatches": 0, "chain_links": 0,
-                      "sharded_dispatches": 0, "degraded": 0}
-        self._plans: List[Optional[_LinkPlan]] = [None] * len(self.links)
-        self._injected: Dict[int, int] = {}   # member col -> first bad link
-        self._fail_retryable: Dict[int, bool] = {}
-        self._records: deque = deque()
-        self._cv = threading.Condition()
-        self._delivered: set = set()
-        self._fail_link = 0
-
-    # -- record stream ---------------------------------------------------- #
-
-    def _push(self, record: Tuple) -> None:
-        with self._cv:
-            self._records.append(record)
-            self._cv.notify_all()
-
-    def _pop(self, stop_event: Optional[threading.Event]) -> Optional[Tuple]:
-        with self._cv:
-            while not self._records:
-                if stop_event is not None and stop_event.is_set():
-                    return None
-                self._cv.wait(timeout=0.5)
-            return self._records.popleft()
-
-    # -- delivery --------------------------------------------------------- #
-
-    def _finish(self, task: Task, exit_code: int, result: Any = None,
-                exception: Optional[str] = None, n_live: int = 1,
-                pilot_lost: bool = False) -> None:
-        drop_member_call(task.uid)   # before the cancel skip: see finish()
-        if task.uid in self.canceled or task.uid in self._delivered:
-            return
-        self._delivered.add(task.uid)
-        now = time.time()
-        if exit_code == 1 and not pilot_lost:
-            self.stats["failed"] += 1
-        self.deliver(TaskCompletion(
-            uid=task.uid, exit_code=exit_code, result=result,
-            exception=exception, started_at=self.started, completed_at=now,
-            execution_seconds=(now - self.started) / max(1, n_live),
-            pilot_lost=pilot_lost))
-
-    # -- worker side ------------------------------------------------------ #
-
-    def dispatch(self) -> None:
-        """Resolve inputs, stack, and enqueue every link's device dispatch.
-
-        Never raises: a preparation/dispatch failure at link *k* streams a
-        ``degrade`` record so the drainer falls back for links *k..L-1*
-        after fanning out the links that did dispatch.
-        """
-        try:
-            if CARRIER_FAULT is not None and CARRIER_FAULT(self):
-                raise RuntimeError("injected carrier fault (chaos plane)")
-            self._dispatch_links()
-        except Exception:  # noqa: BLE001 - drainer owns the fallback
-            self._push(("degrade", self._fail_link,
-                        traceback.format_exc(limit=10)))
-        self._push(("end",))
-
-    def _dispatch_links(self) -> None:
-        if not self.links or not self.links[0]:
-            return
-        if self.cancel_event.is_set():
-            self._push(("canceled",))
-            return
-        # fault injection is a per-member, per-link contract: the first
-        # injected link fails the member there and poisons its downstream
-        for k, tasks in enumerate(self.links):
-            for m, t in enumerate(tasks):
-                if (self.fault_injector is not None and m not in self._injected
-                        and self.fault_injector(t)):
-                    self._injected[m] = k
-        if not self.compose and len(self.links) > 1:
-            # composition declined (fusion_min_chain at the RTS): run the
-            # links per-stage fused INSIDE the carrier — the carrier still
-            # owns the ordering, so link k+1 never races link k's routing
-            self._push(("degrade", 0, None))
-            return
-        self._fail_link = 0
-        mesh = self._mesh
-        with tel.span("fusion.prepare", "fusion") as prep:
-            placed = None if prep is tel.NOOP_SPAN else []
-            self._prepare_links(placed)
-            if mesh is not None:
-                self._place_plans(mesh, placed)
-            if placed is not None:
-                prep.set(placed_bytes=sum(placed), rows=self._rows())
-        # dispatch: maximal runs of vmap-able links compose into ONE jitted
-        # program; a hand-written batched impl executes eagerly between
-        # segments (its jnp ops still enqueue asynchronously). Under a mesh
-        # every dispatch is one shard_map program spanning all devices.
-        idx = 0
-        carry = None
-        while idx < len(self._plans):
-            self._fail_link = idx
-            plan = self._plans[idx]
-            if plan.spec.batched is not None:
-                kw = dict(plan.stacked)
-                if plan.carry_name is not None:
-                    kw[plan.carry_name] = carry
-                if mesh is not None:
-                    out = self._sharded_batched(plan, kw)
-                    self.stats["sharded_dispatches"] += 1
-                else:
-                    out = plan.spec.batched(**kw, **plan.static_kw,
-                                            **plan.shared_kw)
-                self.stats["dispatches"] += 1
-                plan.t_dispatch = time.perf_counter()
-                self._push(("link", idx, out))
-                carry = out
-                idx += 1
-                continue
-            j = idx
-            while (j < len(self._plans)
-                   and self._plans[j].spec.batched is None):
-                j += 1
-            segment = self._plans[idx:j]
-            seg_fn = _composed_segment(segment, mesh=mesh)
-            outs = seg_fn([p.stacked for p in segment],
-                          [p.shared_kw for p in segment], carry)
-            self.stats["dispatches"] += 1
-            if mesh is not None:
-                self.stats["sharded_dispatches"] += 1
-            t_seg = time.perf_counter()
-            for off, out in enumerate(outs):
-                segment[off].t_dispatch = t_seg
-                self._push(("link", idx + off, out))
-            carry = outs[-1]
-            idx = j
-
-    def _prepare_links(self, placed: Optional[List[int]]) -> None:
-        """Resolve and stack every link's members into its plan; a
-        single-device carrier places them on its leased device."""
-        entry_calls = [member_call(t) for t in self.links[0]]
-        mesh = self._mesh
-        # a sharded batch pads to n_shards equal pow2 shards so every mesh
-        # device receives an identical block shape from the P('m') split
-        entry_pad = None if mesh is None \
-            else shard_pad(len(entry_calls), mesh.devices.size)
-        device = None if mesh is not None else lease_device(self.devices)
-        placed_here = placed if mesh is None else None
-        fn, spec, static_kw, shared_kw, stacked, valid_lens, padded_b = \
-            _prepare(entry_calls, pad_to=entry_pad, device=device,
-                     placed=placed_here)
-        self._plans[0] = _LinkPlan(self.links[0], fn, spec, static_kw,
-                                   shared_kw, stacked, valid_lens, None)
-        prev = self.links[0]
-        inherited = valid_lens
-        for j, tasks in enumerate(self.links[1:], start=1):
-            self._fail_link = j
-            calls, carry_name = _continuation_calls(tasks, prev)
-            fnj, specj, st_kw, sh_kw, stk, vl, _ = _prepare(
-                calls, pad_to=padded_b, device=device, placed=placed_here)
-            if vl is None:
-                # a padded axis rides the carry through the whole chain:
-                # downstream links inherit the entry's per-member lengths so
-                # their delivered values trim exactly like the scalar path's
-                vl = inherited
-            else:
-                inherited = vl
-            self._plans[j] = _LinkPlan(tasks, fnj, specj, st_kw, sh_kw, stk,
-                                       vl, carry_name)
-            prev = tasks
-
-    def _rows(self) -> int:
-        """Member rows stacked over the carrier's plans (unpadded)."""
-        return sum(len(p.tasks) for p in self._plans if p is not None)
-
-    @staticmethod
-    def _count_mesh_placement(plan: _LinkPlan, mesh,
-                              placed: Optional[List[int]]) -> None:
-        """Bytes a mesh carrier moves for one plan: stacked kwargs split on
-        the member axis, shared kwargs replicated (the program's call places
-        those, as its in-specs ask)."""
-        if placed is None:
-            return
-        from jax.sharding import NamedSharding, PartitionSpec as P
-
-        placed.append(_placed_nbytes(plan.stacked, NamedSharding(mesh, P("m")))
-                      + _placed_nbytes(plan.shared_kw,
-                                       NamedSharding(mesh, P())))
-
-    def _place_plans(self, mesh, placed: Optional[List[int]] = None) -> None:
-        """Place every link's stacked kwargs across the mesh member axis
-        (shared kwargs replicate). Raises on unplaceable leaves — caught by
-        :meth:`dispatch`, which degrades to the micro-batch ladder."""
-        import jax
-        import jax.numpy as jnp
-        from jax.sharding import NamedSharding, PartitionSpec as P
-
-        sharded = NamedSharding(mesh, P("m"))
-        for plan in self._plans:
-            self._count_mesh_placement(plan, mesh, placed)
-            plan.stacked = {k: jax.device_put(v, sharded)
-                            for k, v in plan.stacked.items()}
-            plan.shared_kw = jax.tree_util.tree_map(
-                jnp.asarray, plan.shared_kw)
-
-    def _sharded_batched(self, plan: _LinkPlan, kw: Dict[str, Any]) -> Any:
-        """Run a hand-batched kernel under ``shard_map``: each mesh device
-        invokes the kernel on its own member shard (the kernel's internal
-        tiling — e.g. the Pallas grid — applies per shard)."""
-        import jax
-        from jax.sharding import PartitionSpec as P
-
-        mesh = self._mesh
-        batched = plan.spec.batched
-        static_kw = plan.static_kw
-
-        def build():
-            def call(kw_, sh_):
-                return batched(**kw_, **sh_, **static_kw)
-            return jax.jit(jax.shard_map(
-                call, mesh=mesh, in_specs=(P("m"), P()),
-                out_specs=P("m"), check_vma=False))
-
-        cache_key = None if plan.statics_key is None else (
-            "shard-batched", _mesh_key(mesh), batched, plan.statics_key,
-            tuple(sorted(kw)), tuple(sorted(plan.shared_kw)))
-        return _jit_cached(cache_key, build)(kw, plan.shared_kw)
-
-    # -- drainer side ----------------------------------------------------- #
-
-    def drain(self, stop_event: Optional[threading.Event] = None
-              ) -> Dict[str, int]:
-        """Block through the link records in order, fanning out per-stage,
-        per-member completions; returns the accumulated statistics."""
-        n = len(self.links[0]) if self.links and self.links[0] else 0
-        ok = np.ones(n, bool)
-        fail_reason: Dict[int, str] = {}
-        # _fail_retryable: member col -> True when the failing link still
-        # has retry budget. Its downstream links then requeue through the
-        # pilot_lost channel (FAILED-without-budget-charge) instead of
-        # failing permanently, so the upstream retry re-runs the member's
-        # whole chain suffix — the outcome the per-stage gated path would
-        # have produced.
-        overrides: Dict[str, Any] = {}
-        fanned = 0
-        degraded = False
-        while True:
-            rec = self._pop(stop_event)
-            if rec is None:        # RTS stopping: abandon without fabricating
-                return self.stats
-            kind = rec[0]
-            if kind == "link":
-                _, k, out = rec
-                if degraded:
-                    continue       # already handled by the fallback
-                if not self._fan_link(k, out, ok, fail_reason, overrides):
-                    degraded = True
-                    fanned = len(self.links)
-                else:
-                    fanned = k + 1
-            elif kind == "degrade":
-                _, start, _exc = rec
-                if not degraded:
-                    if _exc is not None:
-                        # a real dispatch failure (not a declined
-                        # composition): the breaker board keys on this
-                        self.stats["degraded"] += 1
-                    start = max(start, fanned)
-                    self._degrade(start, ok, fail_reason, overrides)
-                    degraded = True
-                    fanned = len(self.links)
-            elif kind == "canceled":
-                for tasks in self.links:
-                    for t in tasks:
-                        self._finish(t, -2)
-                fanned = len(self.links)
-            elif kind == "end":
-                break
-        if fanned < len(self.links):
-            # the worker ended early without a degrade record (engine bug
-            # guard): fall back for whatever never dispatched
-            self._degrade(fanned, ok, fail_reason, overrides)
-        return self.stats
-
-    def _fan_link(self, k: int, out: Any, ok: np.ndarray,
-                  fail_reason: Dict[int, str],
-                  overrides: Dict[str, Any]) -> bool:
-        """Resolve link ``k``'s output and fan it out; False ⇒ resolving
-        failed (an async XLA error surfaced at transfer time) and the
-        remaining links were degraded."""
-        import jax
-
-        plan = self._plans[k]
-        tasks = self.links[k]
-        n = len(tasks)
-        try:
-            out = jax.block_until_ready(out)
-            if plan.t_dispatch is not None:
-                tel.observe_dispatch(_kernel_label(plan.fn), self.tier,
-                                     time.perf_counter() - plan.t_dispatch)
-            fan = _FanOut(out, n, plan.spec.check_finite,
-                          plan.valid_lens if plan.spec.trim_outputs else None,
-                          treedef_key=(plan.fn, plan.statics_key))
-        except Exception:  # noqa: BLE001 - degrade this link and the rest
-            self.stats["degraded"] += 1
-            self._degrade(k, ok, fail_reason, overrides)
-            return False
-        if len(self.links) > 1:
-            self.stats["chain_links"] += 1
-        for i, task in enumerate(tasks):
-            if self.cancel_event.is_set():
-                self._finish(task, -2)
-                continue
-            if not ok[i]:
-                self._finish(task, 1, exception=fail_reason.get(
-                    i, "upstream chain member failed"), n_live=n,
-                    pilot_lost=self._fail_retryable.get(i, False))
-                continue
-            if self._injected.get(i) == k:
-                ok[i] = False
-                fail_reason[i] = (f"upstream chain member failed at link {k} "
-                                  f"(injected fault)")
-                self._fail_retryable[i] = task.retries < task.max_retries
-                self._finish(task, 1, exception="injected fault", n_live=n)
-                continue
-            if not fan.ok[i]:
-                ok[i] = False
-                fail_reason[i] = (f"upstream chain member failed at link {k} "
-                                  f"(non-finite output)")
-                self._fail_retryable[i] = task.retries < task.max_retries
-                self._finish(task, 1, exception=(
-                    "non-finite values in fused dispatch output "
-                    f"(member {task.name})"), n_live=n)
-                continue
-            value = fan.member(i)
-            overrides[task.name] = value
-            self._finish(task, 0, result=value, n_live=n)
-            self.stats["fused"] += 1
-        return True
-
-    def _degrade(self, start: int, ok: np.ndarray,
-                 fail_reason: Dict[int, str],
-                 overrides: Dict[str, Any]) -> None:
-        """Per-stage fused fallback for links ``start..L-1``, in link order,
-        resolving carried inputs from ``overrides`` (this carrier's own
-        fanned-out values) so the fallback can never race the store."""
-        for k in range(start, len(self.links)):
-            tasks = self.links[k]
-            n = len(tasks)
-            todo: List[Tuple[int, Task]] = []
-            for i, task in enumerate(tasks):
-                if self.cancel_event.is_set():
-                    self._finish(task, -2)
-                    continue
-                if not ok[i]:
-                    self._finish(task, 1, exception=fail_reason.get(
-                        i, "upstream chain member failed"), n_live=n,
-                        pilot_lost=self._fail_retryable.get(i, False))
-                    continue
-                if self._injected.get(i) == k:
-                    ok[i] = False
-                    fail_reason[i] = (f"upstream chain member failed at "
-                                      f"link {k} (injected fault)")
-                    self._fail_retryable[i] = \
-                        task.retries < task.max_retries
-                    self._finish(task, 1, exception="injected fault",
-                                 n_live=n)
-                    continue
-                todo.append((i, task))
-            if not todo:
-                continue
-            outcomes: Dict[str, TaskCompletion] = {}
-
-            def dl(c: TaskCompletion) -> None:
-                outcomes[c.uid] = c
-                if c.uid in self.canceled or c.uid in self._delivered:
-                    return
-                self._delivered.add(c.uid)
-                self.deliver(c)
-
-            sub = execute_fused(
-                [t for _, t in todo], self.devices, self.cancel_event, dl,
-                canceled=self.canceled, fault_injector=None,
-                overrides=overrides)
-            for key in ("fused", "scalar_fallback", "failed", "dispatches"):
-                self.stats[key] += sub.get(key, 0)
-            for i, task in todo:
-                c = outcomes.get(task.uid)
-                if c is not None and c.exit_code == 0:
-                    overrides[task.name] = c.result
-                elif c is None or c.exit_code != -2:
-                    ok[i] = False
-                    fail_reason[i] = (f"upstream chain member failed at "
-                                      f"link {k}")
-                    self._fail_retryable[i] = \
-                        task.retries < task.max_retries
 
 
 # --------------------------------------------------------------------------- #
-# DAG execution (fan-in reductions + fan-out broadcasts, one carrier)
+# Carrier execution (async: dispatch on the carrier worker, drain elsewhere)
 # --------------------------------------------------------------------------- #
 
 def _apply_reduction(stacked, mask, kind, combine, axis_name=None):
@@ -1310,28 +792,28 @@ def _reduce_host(leaf) -> Any:
     return arr.item() if arr.ndim == 0 else arr
 
 
-def _dag_continuation_calls(tasks: Sequence[Task],
-                            prev_tasks: Optional[Sequence[Task]],
-                            carry_name: Optional[str],
-                            bcast_name: Optional[str],
-                            bcast_source: Optional[str]
-                            ) -> List[Tuple[Callable, list, dict]]:
-    """Resolve a DAG ensemble node's members WITHOUT touching the carried
-    or broadcast inputs — both arrive device-resident inside the composed
-    program. Unlike the chain's :func:`_continuation_calls` there is no
-    inference: the compiler's tags name the edge kwargs, so the
-    ``carry_name`` kwarg must hold the aligned previous member's future and
-    the ``bcast_name`` kwarg the source reduction's future; any other
-    future is foreign to the DAG and refuses composition."""
+def _continuation_calls(tasks: Sequence[Task],
+                        prev_tasks: Optional[Sequence[Task]],
+                        carry_name: Optional[str],
+                        bcast_name: Optional[str],
+                        bcast_source: Optional[str]
+                        ) -> List[Tuple[Callable, list, dict]]:
+    """Resolve an ensemble node's members WITHOUT touching the carried or
+    broadcast inputs — both arrive device-resident inside the composed
+    program (mid-carrier they have no value in the store yet). The
+    compiler's tags name the edge kwargs, so the ``carry_name`` kwarg must
+    hold the aligned previous member's future and the ``bcast_name`` kwarg
+    the source reduction's future; any other future is foreign to the
+    carrier and refuses composition."""
     from ..api.runtime import FUTURE_KEY
     from ..api.runtime import resolve as resolve_placeholders
 
     calls: List[Tuple[Callable, list, dict]] = []
     for i, t in enumerate(tasks):
         if t.executable != TRAMPOLINE:
-            raise Incongruent("DAG node is not a data-flow task")
+            raise Incongruent("carrier node is not a data-flow task")
         if t.kwargs.get("__args__"):
-            raise Incongruent("DAG node has positional args")
+            raise Incongruent("carrier node has positional args")
         ns = t.kwargs["__ns__"]
         fn = resolve_executable(t.kwargs["__fn__"])
         other: Dict[str, Any] = {}
@@ -1345,16 +827,16 @@ def _dag_continuation_calls(tasks: Sequence[Task],
                 if (bcast_name is not None and k == bcast_name
                         and name == bcast_source):
                     continue
-                raise Incongruent("DAG node consumes a foreign future")
+                raise Incongruent("carrier node consumes a foreign future")
             other[k] = _unwrap(resolve_placeholders(v, ns))
         calls.append((fn, [], other))
     return calls
 
 
-class _DagNodeMeta:
-    """Per-node routing parsed from the ``_fusion_dag`` tags: role, edge
-    kwarg names, and the reduction semantics of ``"r"`` nodes (``combine``
-    is resolved lazily at dispatch time from the reduce task's kernel)."""
+class _NodeMeta:
+    """Per-node routing parsed from the flow tags: role, edge kwarg names,
+    and the reduction semantics of ``"r"`` nodes (``combine`` is resolved
+    lazily at dispatch time from the reduce task's kernel)."""
 
     __slots__ = ("role", "carry_name", "bcast_name", "kind", "combine")
 
@@ -1367,38 +849,52 @@ class _DagNodeMeta:
         self.combine: Optional[Callable[..., Any]] = None
 
 
-class DagExecution(ChainExecution):
-    """One whole fused DAG — ``ensemble → then → gather → broadcast →
-    ensemble`` — through one carrier, asynchronously.
+class CarrierExecution:
+    """One carrier — a fused group, a chain cohort or a whole fused DAG —
+    through its nodes, asynchronously.
 
-    ``links`` holds one aligned task list per DAG *node* in node order:
-    ensemble nodes their member tasks (width w), reduction nodes exactly
-    one reduce task. Roles and edge kwargs come from the ``_fusion_dag``
-    tags the compiler stamped. The dispatcher composes maximal runs of
-    traceable nodes — ensemble nodes without a hand-batched impl, plus
-    every reduction node — into single jitted programs threading the
-    member-stacked ``carry`` and the replicated ``bcast`` (the last
-    reduction's output) between nodes as XLA values; a hand-batched
-    ensemble node executes eagerly between segments with both values
-    staying device-resident. A diamond (``A → reduce → B`` with an
-    elementwise ``A → B`` carry) therefore runs as ONE dispatch.
+    ``links`` holds one aligned task list per *node* in node order:
+    ensemble nodes their member tasks, reduction nodes exactly one reduce
+    task. Roles and edge kwargs come from the tags the compiler stamped: a
+    ``_fusion_dag`` tag names every field; a ``_fusion_chain`` link is an
+    ensemble node carrying elementwise from the one before it under the
+    tag's ``a``; an untagged group is a single ensemble node. The first
+    node never carries from inside the carrier: a cohort re-entering a
+    chain mid-way resolves its entry's carried input from the store.
+
+    The carrier worker calls :meth:`dispatch` — resolve, stack, enqueue the
+    device dispatches, streaming one record per node — and returns. It
+    composes maximal runs of traceable nodes (ensemble nodes without a
+    hand-batched impl, plus every reduction node) into single jitted
+    programs threading the member-stacked ``carry`` and the replicated
+    ``bcast`` (the last reduction's output) between nodes as XLA values
+    (``jit(vmap(g∘f))`` for a 2-link chain); a hand-batched ensemble node
+    executes eagerly between segments with both values staying
+    device-resident. A diamond (``A → reduce → B`` with an elementwise
+    ``A → B`` carry) therefore runs as ONE dispatch. The RTS's completion
+    drainer calls :meth:`drain`, which blocks on each node's device output
+    in order and fans out the per-stage, per-member completions (the
+    journal sees exactly the records a per-stage run would have produced,
+    in the same order).
 
     Reductions execute masked (:func:`_apply_reduction`): padding and
     injected faults are excluded host-side, non-finite members in-program,
     so a poisoned member fails alone at its node while the reduction
     succeeds over the survivors; a reduction with NO live members (or a
     genuinely non-finite result) FAILS, and every downstream broadcast
-    consumer fails with an upstream marker. On the sharded tier the same
-    program runs under ``shard_map``: ensemble nodes stay split on the
-    member axis, reductions combine shard partials with psum/pmax/pmin
-    and come back replicated (out-spec ``P()``).
+    consumer fails with an upstream marker. Under a mesh
+    (``mesh_devices``) the same program runs under ``shard_map``: ensemble
+    nodes stay split on the member axis, reductions combine shard partials
+    with psum/pmax/pmin and come back replicated (out-spec ``P()``).
 
-    Degrade ladder: any preparation or dispatch failure falls back to
-    sequential per-node execution INSIDE the carrier — per-stage fused
-    ensembles (then per-member scalar, inside :func:`execute_fused`) and
-    *scalar* reductions resolving member values from the carrier's own
-    overrides, with store-parity semantics: a scalar reduce over a failed
-    member is a failed reduce, exactly like the un-fused gather path.
+    Degrade ladder: any preparation or dispatch failure at node *k* falls
+    back to sequential per-node execution of nodes *k..N-1* INSIDE the
+    carrier — per-stage fused ensembles through :func:`execute_fused`
+    (then per-member scalar) and *scalar* reductions — resolving carried
+    inputs from the carrier's own overrides, never the store (mid-carrier
+    it may not have been routed yet), with store-parity semantics: a
+    scalar reduce over a failed member is a failed reduce, exactly like
+    the un-fused gather path.
     """
 
     def __init__(self, links: Sequence[Sequence[Task]],
@@ -1410,35 +906,113 @@ class DagExecution(ChainExecution):
                  fault_injector: Optional[Callable[[Task], bool]] = None,
                  compose: bool = True,
                  mesh_devices: Optional[Sequence[Any]] = None) -> None:
-        super().__init__(links, devices, cancel_event, deliver,
-                         canceled=canceled, fault_injector=fault_injector,
-                         compose=compose, mesh_devices=mesh_devices)
-        self.tier = "dag-shard" if self._mesh is not None else "dag"
-        self.stats["dag_links"] = 0
-        self._meta: List[_DagNodeMeta] = []
+        self.links: List[List[Task]] = [list(link) for link in links]
+        self.compose = compose
+        self.devices = devices
+        self.cancel_event = cancel_event
+        self.deliver = deliver
+        self.canceled = canceled if canceled is not None else set()
+        self.fault_injector = fault_injector
+        self.started = time.time()
+        self._mesh = build_mesh(mesh_devices)
+        self._dag = bool(self.links and self.links[0]
+                         and parse_dag_tag(self.links[0][0].tags))
+        self._meta: List[_NodeMeta] = []
         self._cols: List[List[int]] = []
-        for tasks in self.links:
-            tag = parse_dag_tag(tasks[0].tags) if tasks else None
-            tag = tag or {}
-            self._meta.append(_DagNodeMeta(
-                tag.get("r", "e"), tag.get("a"), tag.get("b"),
-                tag.get("rk")))
+        for k, tasks in enumerate(self.links):
+            head = tasks[0].tags if tasks else {}
+            tag = parse_dag_tag(head) or parse_chain_tag(head) or {}
+            self._meta.append(_NodeMeta(
+                tag.get("r", "e"), tag.get("a") if k else None,
+                tag.get("b"), tag.get("rk")))
             # member COLUMN of each task: a resumed fragment's node list
             # can be partial, so list position and member index diverge —
             # per-member state (ok / injected / retryable) keys on the
             # tag's member index, which aligns columns across nodes
             cols = []
             for i, t in enumerate(tasks):
-                tg = parse_dag_tag(t.tags)
+                tg = parse_dag_tag(t.tags) or parse_chain_tag(t.tags)
                 cols.append(tg["m"] if tg else i)
             self._cols.append(cols)
+        if self._dag:
+            self.tier = "dag-shard" if self._mesh is not None else "dag"
+            self._links_stat: Optional[str] = "dag_links"
+        else:
+            self.tier = ("shard" if self._mesh is not None
+                         else "chain" if len(self.links) > 1 else "fused")
+            self._links_stat = "chain_links" if len(self.links) > 1 else None
+        self.stats = {"fused": 0, "scalar_fallback": 0, "failed": 0,
+                      "dispatches": 0, "chain_links": 0, "dag_links": 0,
+                      "sharded_dispatches": 0, "degraded": 0}
+        self._plans: List[Optional[_LinkPlan]] = [None] * len(self.links)
         self._masks: List[Optional[Any]] = [None] * len(self.links)
+        self._injected: Dict[int, int] = {}   # member col -> first bad node
         self._injected_reduce: set = set()   # node index of injected "r"
+        self._fail_retryable: Dict[int, bool] = {}
         self._bcast_ok = True
         self._bcast_reason: Optional[str] = None
         self._bcast_retryable = False
+        self._records: deque = deque()
+        self._cv = threading.Condition()
+        self._delivered: set = set()
+        self._fail_link = 0
+
+    def _upstream(self, k: Optional[int] = None, why: str = "") -> str:
+        """The failure text a member's downstream nodes report."""
+        flow, unit = ("DAG", "node") if self._dag else ("chain", "link")
+        text = f"upstream {flow} member failed"
+        return text if k is None else f"{text} at {unit} {k}{why}"
+
+    # -- record stream ---------------------------------------------------- #
+
+    def _push(self, record: Tuple) -> None:
+        with self._cv:
+            self._records.append(record)
+            self._cv.notify_all()
+
+    def _pop(self, stop_event: Optional[threading.Event]) -> Optional[Tuple]:
+        with self._cv:
+            while not self._records:
+                if stop_event is not None and stop_event.is_set():
+                    return None
+                self._cv.wait(timeout=0.5)
+            return self._records.popleft()
+
+    # -- delivery --------------------------------------------------------- #
+
+    def _finish(self, task: Task, exit_code: int, result: Any = None,
+                exception: Optional[str] = None, n_live: int = 1,
+                pilot_lost: bool = False) -> None:
+        drop_member_call(task.uid)   # before the cancel skip: see finish()
+        if task.uid in self.canceled or task.uid in self._delivered:
+            return
+        self._delivered.add(task.uid)
+        now = time.time()
+        if exit_code == 1 and not pilot_lost:
+            self.stats["failed"] += 1
+        self.deliver(TaskCompletion(
+            uid=task.uid, exit_code=exit_code, result=result,
+            exception=exception, started_at=self.started, completed_at=now,
+            execution_seconds=(now - self.started) / max(1, n_live),
+            pilot_lost=pilot_lost))
 
     # -- worker side ------------------------------------------------------ #
+
+    def dispatch(self) -> None:
+        """Resolve inputs, stack, and enqueue every node's device dispatch.
+
+        Never raises: a preparation/dispatch failure at node *k* streams a
+        ``degrade`` record so the drainer falls back for nodes *k..N-1*
+        after fanning out the nodes that did dispatch.
+        """
+        try:
+            if CARRIER_FAULT is not None and CARRIER_FAULT(self):
+                raise RuntimeError("injected carrier fault (chaos plane)")
+            self._dispatch_links()
+        except Exception:  # noqa: BLE001 - drainer owns the fallback
+            self._push(("degrade", self._fail_link,
+                        traceback.format_exc(limit=10)))
+        self._push(("end",))
 
     def _dispatch_links(self) -> None:
         if not self.links or not self.links[0]:
@@ -1463,9 +1037,10 @@ class DagExecution(ChainExecution):
                     self._injected[col] = k
         self._fail_link = 0
         if not self.compose:
-            # composition declined (dag knob off at the RTS): sequential
-            # per-node INSIDE the carrier — the carrier still owns the
-            # ordering, so the reduce never races its members' routing
+            # composition declined (fusion_min_chain, the dag knob or an
+            # open breaker at the RTS): sequential per-node INSIDE the
+            # carrier — the carrier still owns the ordering, so node k+1
+            # (or a reduce) never races node k's routing
             self._push(("degrade", 0, None))
             return
         mesh = self._mesh
@@ -1476,7 +1051,7 @@ class DagExecution(ChainExecution):
                 # the reduction masks are built on the host
                 placed.append(_placed_nbytes(self._masks, None))
             if mesh is not None:
-                self._place_dag(mesh, placed)
+                self._place(mesh, placed)
             if placed is not None:
                 prep.set(placed_bytes=sum(placed), rows=self._rows())
         idx = 0
@@ -1511,7 +1086,7 @@ class DagExecution(ChainExecution):
                                  and self._plans[j].spec.batched
                                  is not None):
                 j += 1
-            outs = self._dag_segment(idx, j, carry, bcast, mesh)
+            outs = self._segment(idx, j, carry, bcast, mesh)
             self.stats["dispatches"] += 1
             if mesh is not None:
                 self.stats["sharded_dispatches"] += 1
@@ -1529,18 +1104,22 @@ class DagExecution(ChainExecution):
     def _prepare_nodes(self, placed: Optional[List[int]] = None) -> None:
         """Build every node's plan, reduction mask and combine; raises
         :class:`Incongruent` on any unsupported shape — caught by
-        :meth:`dispatch`, which degrades the WHOLE DAG to sequential
-        per-node execution (prep happens before any dispatch)."""
+        :meth:`dispatch`, which degrades the WHOLE carrier to sequential
+        per-node execution (prep happens before any dispatch). A
+        single-device carrier places the stacked kwargs on its leased
+        device; a mesh carrier places them in :meth:`_place`."""
         mesh = self._mesh
         placed_here = placed if mesh is None else None
         if self._meta[0].role != "e":
-            raise Incongruent("DAG does not start at an ensemble node")
+            raise Incongruent("carrier does not start at an ensemble node")
         if mesh is not None:
             widths = {len(t) for t, mt in zip(self.links, self._meta)
                       if mt.role == "e"}
             if len(widths) != 1:
-                raise Incongruent("sharded DAG requires equal node widths")
+                raise Incongruent("sharded carrier requires equal node widths")
         entry_calls = [member_call(t) for t in self.links[0]]
+        # a sharded batch pads to n_shards equal pow2 shards so every mesh
+        # device receives an identical block shape from the P('m') split
         entry_pad = None if mesh is None else shard_pad(
             len(entry_calls), mesh.devices.size)
         device = None if mesh is not None else lease_device(self.devices)
@@ -1548,7 +1127,7 @@ class DagExecution(ChainExecution):
             _prepare(entry_calls, pad_to=entry_pad, device=device,
                      placed=placed_here)
         self._plans[0] = _LinkPlan(self.links[0], fn, spec, static_kw,
-                                   shared_kw, stacked, valid_lens, None)
+                                   shared_kw, stacked, valid_lens)
         pad_of = {0: padded_b}       # e-node index -> padded batch axis
         lens_of = {0: valid_lens}    # e-node index -> row-pad lengths
         last_e = 0
@@ -1578,7 +1157,7 @@ class DagExecution(ChainExecution):
                 continue
             if meta.bcast_name is not None and last_r_name is None:
                 raise Incongruent("broadcast precedes any reduction")
-            calls = _dag_continuation_calls(
+            calls = _continuation_calls(
                 tasks,
                 self.links[last_e] if meta.carry_name is not None else None,
                 meta.carry_name, meta.bcast_name, last_r_name)
@@ -1586,6 +1165,8 @@ class DagExecution(ChainExecution):
                     and len(tasks) != len(self.links[last_e])):
                 raise Incongruent("carry nodes disagree on member count")
             if meta.carry_name is not None:
+                # the carried axis must stay congruent through the whole
+                # composed program
                 pad_to: Optional[int] = pad_of[last_e]
             else:
                 pad_to = None if mesh is None else shard_pad(
@@ -1593,9 +1174,12 @@ class DagExecution(ChainExecution):
             fnk, speck, st_kw, sh_kw, stk, vl, pb = _prepare(
                 calls, pad_to=pad_to, device=device, placed=placed_here)
             if vl is None and meta.carry_name is not None:
-                vl = lens_of[last_e]   # padded rows ride the carry through
+                # a padded axis rides the carry: downstream nodes inherit
+                # the entry's per-member lengths so their delivered values
+                # trim exactly like the scalar path's
+                vl = lens_of[last_e]
             self._plans[k] = _LinkPlan(tasks, fnk, speck, st_kw, sh_kw,
-                                       stk, vl, meta.carry_name)
+                                       stk, vl)
             last_e = k
             pad_of[k] = pb
             lens_of[k] = vl
@@ -1621,9 +1205,29 @@ class DagExecution(ChainExecution):
             mask[i] = k_inj is None or k_inj > src
         return mask
 
-    def _place_dag(self, mesh, placed: Optional[List[int]] = None) -> None:
+    def _rows(self) -> int:
+        """Member rows stacked over the carrier's plans (unpadded)."""
+        return sum(len(p.tasks) for p in self._plans if p is not None)
+
+    @staticmethod
+    def _count_mesh_placement(plan: _LinkPlan, mesh,
+                              placed: Optional[List[int]]) -> None:
+        """Bytes a mesh carrier moves for one plan: stacked kwargs split on
+        the member axis, shared kwargs replicated (the program's call places
+        those, as its in-specs ask)."""
+        if placed is None:
+            return
+        from jax.sharding import NamedSharding, PartitionSpec as P
+
+        placed.append(_placed_nbytes(plan.stacked, NamedSharding(mesh, P("m")))
+                      + _placed_nbytes(plan.shared_kw,
+                                       NamedSharding(mesh, P())))
+
+    def _place(self, mesh, placed: Optional[List[int]] = None) -> None:
         """Place every ensemble node's stacked kwargs and every reduction
-        mask across the mesh member axis (shared kwargs replicate)."""
+        mask across the mesh member axis (shared kwargs replicate). Raises
+        on unplaceable leaves — caught by :meth:`dispatch`, which degrades
+        to the sequential per-node ladder."""
         import jax
         import jax.numpy as jnp
         from jax.sharding import NamedSharding, PartitionSpec as P
@@ -1640,10 +1244,40 @@ class DagExecution(ChainExecution):
             plan.shared_kw = jax.tree_util.tree_map(
                 jnp.asarray, plan.shared_kw)
 
-    def _dag_segment(self, start: int, stop: int, carry, bcast, mesh):
+    def _sharded_batched(self, plan: _LinkPlan, kw: Dict[str, Any]) -> Any:
+        """Run a hand-batched kernel under ``shard_map``: each mesh device
+        invokes the kernel on its own member shard (the kernel's internal
+        tiling — e.g. the Pallas grid — applies per shard)."""
+        import jax
+        from jax.sharding import PartitionSpec as P
+
+        mesh = self._mesh
+        batched = plan.spec.batched
+        static_kw = plan.static_kw
+
+        def build():
+            def call(kw_, sh_):
+                return batched(**kw_, **sh_, **static_kw)
+            return jax.jit(jax.shard_map(
+                call, mesh=mesh, in_specs=(P("m"), P()),
+                out_specs=P("m"), check_vma=False))
+
+        cache_key = None if plan.statics_key is None else (
+            "shard-batched", _mesh_key(mesh), batched, plan.statics_key,
+            tuple(sorted(kw)), tuple(sorted(plan.shared_kw)))
+        return _jit_cached(cache_key, build)(kw, plan.shared_kw)
+
+    def _segment(self, start: int, stop: int, carry, bcast, mesh):
         """Run nodes ``[start, stop)`` as one jitted program — ensemble
         nodes vmap, reduction nodes reduce — with carry and bcast threaded
-        inside the program as XLA values (one dispatch for the run)."""
+        inside the program as XLA values (one dispatch for the run). The
+        carried intermediate never materializes on the host, and XLA is
+        free to fuse across the node boundary; every node's output is
+        still returned (the fan-out owes each stage its per-member
+        completions). With ``mesh``, the whole segment runs under
+        ``shard_map`` on the member axis: one program spans every mesh
+        device and the carried intermediates stay sharded across node
+        boundaries."""
         import jax
 
         plans = [self._plans[k] for k in range(start, stop)]
@@ -1733,24 +1367,31 @@ class DagExecution(ChainExecution):
 
     def drain(self, stop_event: Optional[threading.Event] = None
               ) -> Dict[str, int]:
-        """Chain drain loop over NODE records; member state is sized to the
-        highest member column (widths change across a fan-in, and resumed
-        fragments can hold sparse columns)."""
+        """Block through the node records in order, fanning out per-stage,
+        per-member completions; returns the accumulated statistics. Member
+        state is sized to the highest member column (widths change across
+        a fan-in, and resumed fragments can hold sparse columns)."""
         width = max((max(c) + 1 for c in self._cols if c), default=1)
         ok = np.ones(width, bool)
         fail_reason: Dict[int, str] = {}
+        # _fail_retryable: member col -> True when the failing node still
+        # has retry budget. Its downstream nodes then requeue through the
+        # pilot_lost channel (FAILED-without-budget-charge) instead of
+        # failing permanently, so the upstream retry re-runs the member's
+        # whole suffix — the outcome the per-stage gated path would have
+        # produced.
         overrides: Dict[str, Any] = {}
         fanned = 0
         degraded = False
         while True:
             rec = self._pop(stop_event)
-            if rec is None:
+            if rec is None:        # RTS stopping: abandon without fabricating
                 return self.stats
             kind = rec[0]
             if kind == "link":
                 _, k, out = rec
                 if degraded:
-                    continue
+                    continue       # already handled by the fallback
                 if not self._fan_node(k, out, ok, fail_reason, overrides):
                     degraded = True
                     fanned = len(self.links)
@@ -1775,12 +1416,17 @@ class DagExecution(ChainExecution):
             elif kind == "end":
                 break
         if fanned < len(self.links):
+            # the worker ended early without a degrade record (engine bug
+            # guard): fall back for whatever never dispatched
             self._degrade(fanned, ok, fail_reason, overrides)
         return self.stats
 
     def _fan_node(self, k: int, out: Any, ok: np.ndarray,
                   fail_reason: Dict[int, str],
                   overrides: Dict[str, Any]) -> bool:
+        """Resolve node ``k``'s output and fan it out; False ⇒ resolving
+        failed (an async XLA error surfaced at transfer time) and the
+        remaining nodes were degraded."""
         if self._meta[k].role == "r":
             return self._fan_reduce(k, out, ok, fail_reason, overrides)
         import jax
@@ -1801,7 +1447,8 @@ class DagExecution(ChainExecution):
             self.stats["degraded"] += 1
             self._degrade(k, ok, fail_reason, overrides)
             return False
-        self.stats["dag_links"] += 1
+        if self._links_stat is not None:
+            self.stats[self._links_stat] += 1
         bcast_bad = meta.bcast_name is not None and not self._bcast_ok
         has_carry = meta.carry_name is not None
         for i, task in enumerate(tasks):
@@ -1818,20 +1465,18 @@ class DagExecution(ChainExecution):
                 continue
             if has_carry and not ok[col]:
                 self._finish(task, 1, exception=fail_reason.get(
-                    col, "upstream DAG member failed"), n_live=n,
+                    col, self._upstream()), n_live=n,
                     pilot_lost=self._fail_retryable.get(col, False))
                 continue
             if self._injected.get(col) == k:
                 ok[col] = False
-                fail_reason[col] = (f"upstream DAG member failed at node "
-                                    f"{k} (injected fault)")
+                fail_reason[col] = self._upstream(k, " (injected fault)")
                 self._fail_retryable[col] = task.retries < task.max_retries
                 self._finish(task, 1, exception="injected fault", n_live=n)
                 continue
             if not fan.ok[i]:
                 ok[col] = False
-                fail_reason[col] = (f"upstream DAG member failed at node "
-                                    f"{k} (non-finite output)")
+                fail_reason[col] = self._upstream(k, " (non-finite output)")
                 self._fail_retryable[col] = task.retries < task.max_retries
                 self._finish(task, 1, exception=(
                     "non-finite values in fused dispatch output "
@@ -1901,8 +1546,9 @@ class DagExecution(ChainExecution):
         order inside the carrier: ensemble nodes per-stage fused (then
         per-member scalar inside :func:`execute_fused`), reduction nodes
         SCALAR — resolving member values from the carrier's own overrides
-        first, then the store, so a failed member makes the reduce fail
-        exactly like the un-fused gather path."""
+        first, then the store, so the fallback can never race the store
+        and a failed member makes the reduce fail exactly like the
+        un-fused gather path."""
         for k in range(start, len(self.links)):
             meta = self._meta[k]
             if meta.role == "r":
@@ -1927,13 +1573,12 @@ class DagExecution(ChainExecution):
                     continue
                 if has_carry and not ok[col]:
                     self._finish(task, 1, exception=fail_reason.get(
-                        col, "upstream DAG member failed"), n_live=n,
+                        col, self._upstream()), n_live=n,
                         pilot_lost=self._fail_retryable.get(col, False))
                     continue
                 if self._injected.get(col) == k:
                     ok[col] = False
-                    fail_reason[col] = (f"upstream DAG member failed at "
-                                        f"node {k} (injected fault)")
+                    fail_reason[col] = self._upstream(k, " (injected fault)")
                     self._fail_retryable[col] = \
                         task.retries < task.max_retries
                     self._finish(task, 1, exception="injected fault",
@@ -1964,8 +1609,7 @@ class DagExecution(ChainExecution):
                     overrides[task.name] = c.result
                 elif c is None or c.exit_code != -2:
                     ok[col] = False
-                    fail_reason[col] = (f"upstream DAG member failed at "
-                                        f"node {k}")
+                    fail_reason[col] = self._upstream(k)
                     self._fail_retryable[col] = \
                         task.retries < task.max_retries
 

@@ -65,7 +65,9 @@ reduce via ``psum``/``pmax`` across the mesh), while resume fragments and
 ``dag=False`` run the nodes sequentially inside the same carrier —
 preserving ordering, per-member journal records and reduction semantics on
 the degrade ladder (DAG → in-carrier sequential → per-stage fused →
-scalar).
+scalar). Fused groups, chains and DAGs all run in the engine's one
+executor (:class:`~repro.fusion.engine.CarrierExecution`), which reads each
+node's role and carried kwarg from its tags.
 
 On this CPU container the inventory is logical (``slot_oversubscribe``
 logical slots share the physical CPU device) — the accounting, leasing and
@@ -1111,9 +1113,7 @@ class JaxRTS(LocalRTS):
             uniq = list(dict.fromkeys(devices))
             if len(uniq) >= batch.mesh_shards:
                 mesh_devices = uniq[:batch.mesh_shards]
-        cls = (fusion_engine.DagExecution if batch.dag
-               else fusion_engine.ChainExecution)
-        exe = cls(
+        exe = fusion_engine.CarrierExecution(
             batch.links, devices, cancel_event, deliver,
             canceled=self._fused_canceled,
             fault_injector=self.fault_injector, compose=batch.compose,

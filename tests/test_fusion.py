@@ -13,10 +13,11 @@ from repro.core import AppManager
 from repro.core import states as st
 from repro.core.pst import Task
 from repro.core.results import decode_journal_value
-from repro.fusion import ArrayResult, fusable, fusion_group_key, plan_group
+from repro.fusion import (ArrayResult, fusable, fusable_reduction,
+                          fusion_group_key, plan_group)
 from repro.fusion import engine as fengine
 from repro.rts.base import RequeueTask, ResourceDescription
-from repro.rts.jax_rts import JaxRTS
+from repro.rts.jax_rts import CARRIERS_TOTAL, JaxRTS
 
 
 # --------------------------------------------------------------------------- #
@@ -777,6 +778,66 @@ def test_chain_upstream_retry_revives_downstream_links():
     for i, s in enumerate(e1.specs):
         assert float(np.asarray(s.out.result())) == float(i) ** 4
     res.close()
+
+
+@fusable_reduction(kind="sum")
+def r_sum(values):
+    return float(np.sum([np.asarray(v) for v in values]))
+
+
+def _tier_workflow(shape, fuse):
+    """(run node, specs whose values must match) for one carrier shape: a
+    fused group, a 2-link chain, or a gather DAG (ensemble -> reduce)."""
+    e0 = api.ensemble(k_square,
+                      over=[{"x": float(i), "scale": 1.0} for i in range(8)],
+                      name=f"ot{shape}{int(fuse)}", fuse=fuse)
+    if shape == "fused":
+        return e0, list(e0.specs)
+    if shape == "chain":
+        e1 = e0.then(k_square, name=f"ot{shape}{int(fuse)}b", fuse=fuse)
+        return e1, list(e0.specs) + list(e1.specs)
+    total = api.gather(e0, r_sum, name=f"ot{shape}{int(fuse)}r")
+    return total, list(e0.specs) + [total]
+
+
+@pytest.mark.parametrize("shape,tier,links_stat", [
+    ("fused", "fused", None),
+    ("chain", "chain", "chain_links"),
+    ("dag", "dag", "dag_links"),
+])
+def test_one_executor_runs_every_tier(shape, tier, links_stat):
+    """Fused groups, chains and gather DAGs all run through the one
+    carrier executor: each carrier counts under the tier its tags imply,
+    fans out the matching per-node stat, and delivers member values
+    bit-identical to an unfused run."""
+    def run(fuse):
+        node, specs = _tier_workflow(shape, fuse)
+        holder = {}
+
+        def factory():
+            holder["rts"] = JaxRTS(devices=["d0"], slot_oversubscribe=4)
+            return holder["rts"]
+
+        res = api.run(node, resources=ResourceDescription(slots=4),
+                      rts_factory=factory, timeout=60)
+        assert all(v == st.DONE for v in res.task_states.values())
+        vals = [float(np.asarray(s.out.result())) for s in specs]
+        rts = holder["rts"]
+        tiers = {labels["tier"]: c.value for labels, c
+                 in rts.metrics.collect("counter", CARRIERS_TOTAL)
+                 if c.value}
+        stats = dict(rts.fusion_stats)
+        res.close()
+        return vals, tiers, stats
+
+    s_vals, s_tiers, _ = run(fuse=False)
+    f_vals, f_tiers, f_stats = run(fuse=True)
+    assert s_tiers == {}                  # the unfused run made no carrier
+    assert set(f_tiers) == {tier}
+    for key in ("chain_links", "dag_links"):
+        assert (f_stats[key] > 0) == (key == links_stat), (key, f_stats)
+    assert f_stats["fused"] > 0
+    assert f_vals == s_vals               # bit-identical member results
 
 
 def test_running_since_and_cancel_for_undrained_async_carrier():

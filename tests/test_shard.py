@@ -298,9 +298,9 @@ def test_sharded_one_device_mesh_matches_scalar():
 def test_sharded_dispatch_failure_degrades_not_fails(monkeypatch):
     # an exception inside the sharded dispatch (here: placement) must not
     # fail the members — the carrier degrades to the micro-batch ladder
-    def boom(self, mesh):
+    def boom(self, mesh, placed=None):
         raise RuntimeError("injected placement failure")
-    monkeypatch.setattr(fengine.ChainExecution, "_place_plans", boom)
+    monkeypatch.setattr(fengine.CarrierExecution, "_place", boom)
     ens = api.ensemble(k_shard_square,
                        over=[{"x": float(i)} for i in range(8)], name="dg")
     holder = {}
