@@ -91,7 +91,14 @@ class Compiled:
         return len(self.pipelines)
 
     def close(self) -> int:
-        """Release this workflow's results from the process-global store."""
+        """Release this workflow's results from the process-global store
+        and from its tasks. A task's result may hold a fused output on the
+        device, and the workflow's objects refer to each other, so without
+        this the device memory would wait for the cyclic collector."""
+        for pipeline in self.pipelines:
+            for stage in pipeline.stages:
+                for task in stage.tasks:
+                    task.result = None
         return STORE.clear_namespace(self.ns)
 
     def __repr__(self) -> str:  # pragma: no cover - debugging aid

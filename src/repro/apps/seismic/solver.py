@@ -21,12 +21,15 @@ Fig.-10 scale experiment.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from ...kernels import seismic_stencil
 
 
 @dataclasses.dataclass(frozen=True)
@@ -192,20 +195,130 @@ def _ricker(cfg: SeismicConfig) -> jnp.ndarray:
     return (1 - 2 * a) * jnp.exp(-a)
 
 
-def _damping(cfg: SeismicConfig) -> jnp.ndarray:
-    """strength·((w − i)/w)² at i cells from the padded grid's nearest edge,
-    for i < w = damp_width, else 0: the profile's w + 1 values indexed by
-    the distance, so the grid-sized field is built in the program and is
-    no constant of it."""
-    nzp, nxp = cfg.padded_shape
+def _damping_profile(cfg: SeismicConfig) -> np.ndarray:
+    """strength·((w − i)/w)² for i < w = damp_width, else 0, at i = 0 .. w,
+    in float32: the damping at i cells from the padded grid's nearest edge
+    (i clipped to w)."""
     w = cfg.damp_width
     profile = np.zeros(w + 1)
     profile[:w] = cfg.damp_strength * ((w - np.arange(w)) / w) ** 2
+    return profile.astype(np.float32)
+
+
+def _damping(cfg: SeismicConfig) -> jnp.ndarray:
+    """The damping profile indexed by each point's distance from the padded
+    grid's nearest edge, so the grid-sized field is built in the program
+    and is no constant of it."""
+    nzp, nxp = cfg.padded_shape
     zz = jax.lax.broadcasted_iota(jnp.int32, (nzp, nxp), 0)
     xx = jax.lax.broadcasted_iota(jnp.int32, (nzp, nxp), 1)
     edge = jnp.minimum(jnp.minimum(zz, nzp - 1 - zz),
                        jnp.minimum(xx, nxp - 1 - xx))
-    return jnp.asarray(profile, jnp.float32)[jnp.minimum(edge, w)]
+    return jnp.asarray(_damping_profile(cfg))[
+        jnp.minimum(edge, cfg.damp_width)]
+
+
+def laplacian(u: jnp.ndarray) -> jnp.ndarray:
+    """−4u + u[z−1] + u[z+1] + u[x−1] + u[x+1], summed in that order, the
+    indices wrapping around the grid."""
+    return (-4.0 * u
+            + jnp.roll(u, 1, 0) + jnp.roll(u, -1, 0)
+            + jnp.roll(u, 1, 1) + jnp.roll(u, -1, 1))
+
+
+def leapfrog_step(u_prev, u, c2dt2, damp, sz, sx, s_t) -> jnp.ndarray:
+    """One step of the damped leapfrog on the padded grid, the source
+    sample ``s_t`` added at (``sz``, ``sx``): the definition that the
+    Pallas step (:mod:`repro.kernels.seismic_stencil`) reproduces."""
+    u_next = ((2.0 - damp) * u - (1.0 - damp) * u_prev
+              + c2dt2 * laplacian(u))
+    return u_next.at[sz, sx].add(s_t)
+
+
+def stencil_path(cfg: SeismicConfig) -> str:
+    """The step the forward takes: "pallas", the one-pass kernel, on a TPU
+    for a padded grid of at least one (8, 128) tile; else "jnp",
+    :func:`leapfrog_step`."""
+    if jax.default_backend() == "tpu" and seismic_stencil.fits(
+            *cfg.padded_shape):
+        return "pallas"
+    return "jnp"
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _kernel_step(cfg: SeismicConfig, u_prev, u, c, sx, s_t):
+    """:func:`leapfrog_step` by the Pallas kernel, the fields in the
+    kernel's aligned storage; differentiated through the jnp step."""
+    return seismic_stencil.leapfrog(
+        u_prev, u, c, cfg.source_z + cfg.pad, sx, s_t,
+        lay=seismic_stencil.layout(*cfg.padded_shape),
+        profile=tuple(map(float, _damping_profile(cfg))))
+
+
+def _kernel_step_fwd(cfg, u_prev, u, c, sx, s_t):
+    return _kernel_step(cfg, u_prev, u, c, sx, s_t), (u_prev, u, c, sx, s_t)
+
+
+def _kernel_step_bwd(cfg, saved, cotangent):
+    u_prev, u, c, sx, s_t = saved
+    lay = seismic_stencil.layout(*cfg.padded_shape)
+    crop = functools.partial(seismic_stencil.crop, lay=lay)
+    damp = _damping(cfg)
+
+    def step(u_prev, u, c, s_t):
+        return seismic_stencil.embed(leapfrog_step(
+            crop(u_prev), crop(u), crop(c), damp, cfg.source_z + cfg.pad,
+            sx, s_t), lay)
+
+    _, vjp = jax.vjp(step, u_prev, u, c, s_t)
+    d_prev, d_u, d_c, d_s = vjp(cotangent)
+    return d_prev, d_u, d_c, None, d_s
+
+
+_kernel_step.defvjp(_kernel_step_fwd, _kernel_step_bwd)
+
+
+def _steps(step, carry, s):
+    """``len(s)`` steps from ``carry`` = (u_prev, u), two a loop iteration,
+    so that the loop hands each field back in the buffer it came in."""
+    def two(carry, s2):
+        a, b = carry
+        a = step(a, b, s2[0])
+        return (a, step(b, a, s2[1])), None
+
+    pairs = s.shape[0] // 2
+    if pairs:
+        carry, _ = jax.lax.scan(two, carry, s[:2 * pairs].reshape(pairs, 2))
+    if s.shape[0] % 2:
+        carry = (carry[1], step(*carry, s[-1]))
+    return carry
+
+
+def _record(step, carry, src, every: int, sample):
+    """Every step of ``src``, ``sample`` of the wavefield after the first of
+    each ``every`` steps; a loop iteration runs an even number of steps."""
+    def block(carry, s):
+        carry = _steps(step, carry, s[:1])
+        rec = sample(carry[1])
+        return _steps(step, carry, s[1:]), rec
+
+    def blocks(carry, s_group):
+        recs = []
+        for s in s_group:
+            carry, rec = block(carry, s)
+            recs.append(rec)
+        return carry, jnp.stack(recs)
+
+    src = src.reshape(-1, every)
+    group = 1 if every % 2 == 0 else 2
+    n = src.shape[0] // group * group
+    carry, seis = jax.lax.scan(blocks, carry,
+                               src[:n].reshape(-1, group, every))
+    seis = seis.reshape((n,) + seis.shape[2:])
+    if n < src.shape[0]:
+        _, rec = block(carry, src[n])
+        seis = jnp.concatenate([seis, rec[None]])
+    return seis
 
 
 def forward_simulation(velocity: jnp.ndarray, source_x: int,
@@ -214,41 +327,36 @@ def forward_simulation(velocity: jnp.ndarray, source_x: int,
     seismogram (nt / record_every, n_receivers) recorded at row
     ``cfg.receiver_z`` of columns ``receiver_x`` (default: the fixed
     spread); sample k is the wavefield after step k·record_every. Positions
-    are on the nz × nx grid; the velocity model is nz × nx."""
+    are on the nz × nx grid; the velocity model is nz × nx. Each step is
+    :func:`leapfrog_step`, run by the path :func:`stencil_path` picks."""
     with jax.named_scope("seismic.forward"):
         p = cfg.pad
         if p:
             velocity = jnp.pad(velocity, p, mode="edge")
         src = _ricker(cfg)
-        damp = _damping(cfg)
         c2dt2 = (velocity * cfg.dt) ** 2 / (cfg.dx ** 2)
         if receiver_x is None:   # the fixed spread, evenly over the grid
             receiver_x = jnp.linspace(4, cfg.nx - 5, cfg.n_receivers)
         rec_x = jnp.asarray(receiver_x).astype(jnp.int32) + p
-        sz, rz, sx = cfg.source_z + p, cfg.receiver_z + p, source_x + p
+        sz, rz = cfg.source_z + p, cfg.receiver_z + p
+        sx = jnp.asarray(source_x, jnp.int32) + p
+        if stencil_path(cfg) == "pallas":
+            lay = seismic_stencil.layout(*cfg.padded_shape)
+            c = seismic_stencil.embed(c2dt2, lay)
+            shape = (lay.rows, lay.cols)
 
-        def laplacian(u):
-            return (-4.0 * u
-                    + jnp.roll(u, 1, 0) + jnp.roll(u, -1, 0)
-                    + jnp.roll(u, 1, 1) + jnp.roll(u, -1, 1))
+            def step(u_prev, u, s_t):
+                return _kernel_step(cfg, u_prev, u, c, sx, s_t)
+        else:
+            damp = _damping(cfg)
+            shape = cfg.padded_shape
 
-        def step(carry, s_t):
-            u_prev, u = carry
-            u_next = ((2.0 - damp) * u - (1.0 - damp) * u_prev
-                      + c2dt2 * laplacian(u))
-            return (u, u_next.at[sz, sx].add(s_t)), None
+            def step(u_prev, u, s_t):
+                return leapfrog_step(u_prev, u, c2dt2, damp, sz, sx, s_t)
 
-        def recorded_step(carry, s_block):
-            # a sample after the block's first step, then the rest unseen
-            carry, _ = step(carry, s_block[0])
-            rec = carry[1][rz, rec_x]
-            carry, _ = jax.lax.scan(step, carry, s_block[1:])
-            return carry, rec
-
-        zeros = jnp.zeros(cfg.padded_shape, jnp.float32)
-        _, seis = jax.lax.scan(recorded_step, (zeros, zeros),
-                               src.reshape(-1, cfg.record_every))
-        return seis
+        zeros = jnp.zeros(shape, jnp.float32)
+        return _record(step, (zeros, zeros), src, cfg.record_every,
+                       lambda u: u[rz][rec_x])
 
 
 def misfit(velocity: jnp.ndarray, observed: jnp.ndarray, source_x: int,

@@ -27,7 +27,8 @@ from ...fusion import fusable, fusable_reduction
 from ...rts.base import ResourceDescription
 from ...rts.jax_rts import JaxRTS
 from ...rts.local import LocalRTS
-from .solver import SeismicConfig, forward_simulation, make_velocity_model, misfit
+from .solver import (SeismicConfig, forward_simulation, make_velocity_model,
+                     misfit, stencil_path)
 
 _CACHE: Dict[object, object] = {}
 
@@ -268,7 +269,8 @@ def run_sweep(cfg: SeismicConfig, geometry: Sequence[Shot],
         tel.event("seismic.sweep", "seismic", shots=len(geometry),
                   steps=cfg.nt, grid_points=nzp * nxp,
                   record_samples=cfg.record_samples,
-                  observed_bytes=int(observed.nbytes))
+                  observed_bytes=int(observed.nbytes),
+                  stencil=stencil_path(cfg))
     forward = api.ensemble(
         shot_synthetic,
         over=[{"source_x": int(sx), "receiver_x": np.asarray(rx, np.int32),

@@ -453,3 +453,71 @@ def test_aua_proposal_span_inside_the_loop_body():
                           "reused_estimate"}
         assert (a["picks"], a["explore"], a["reused_estimate"]) == (15, 5, 1)
         assert 0 < a["reach"] and 0 < a["cells_updated"] <= 20 * 24 * 24
+
+
+@pytest.mark.parametrize("backend,nz,nx,pad,path", [
+    ("tpu", 751, 2301, 50, "pallas"),   # the Marmousi survey
+    ("tpu", 512, 512, 0, "pallas"),     # chip_smoke's sweep
+    ("tpu", 4, 124, 2, "pallas"),       # padded to exactly one (8, 128) tile
+    ("tpu", 96, 96, 0, "jnp"),          # simulate_earthquake: narrower
+    ("tpu", 7, 200, 0, "jnp"),          # fewer rows than a tile
+    ("cpu", 751, 2301, 50, "jnp"),
+])
+def test_the_forward_takes_the_stencil_kernel_on_a_tpu_for_a_tile(
+        monkeypatch, backend, nz, nx, pad, path):
+    from repro.apps.seismic import solver
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    cfg = SeismicConfig(nx=nx, nz=nz, dx=4.0, dt=5e-4, pad=pad,
+                        damp_width=max(pad, 1))
+    assert solver.stencil_path(cfg) == path
+
+
+def test_sweep_event_names_the_stencil_path():
+    """``run_sweep``'s ``seismic.sweep`` event says which step the
+    campaign's forward took: the jnp step on the CPU."""
+    from repro import telemetry
+    from repro.apps.seismic.solver import shot_geometry, stencil_path
+    from repro.apps.seismic.workflow import record_survey, run_sweep
+
+    geometry = [shot_geometry(SURVEY, j) for j in (3, 4)]
+    observed = record_survey(SURVEY, geometry, seed=1)
+    telemetry.enable()
+    telemetry.TRACER.clear()
+    try:
+        res = run_sweep(SURVEY, geometry, observed, seed=1, slots=2,
+                        timeout=300.0)
+        recs = telemetry.TRACER.snapshot()
+    finally:
+        telemetry.disable()
+        telemetry.TRACER.clear()
+    assert res["all_done"]
+    events = [e for e in recs if e["name"] == "seismic.sweep"]
+    assert len(events) == 1 and events[0]["ph"] == "i"
+    assert events[0]["attrs"]["stencil"] == stencil_path(SURVEY) == "jnp"
+
+
+def test_sweep_leaves_no_campaign_output_on_the_device():
+    """After ``run_sweep`` returns, the campaign's fused outputs are freed
+    at once: closing the workflow drops its tasks' results, so device
+    memory does not wait for the cyclic collector and does not grow with
+    the number of campaigns run."""
+    import gc
+    from repro.apps.seismic.solver import shot_geometry
+    from repro.apps.seismic.workflow import record_survey, run_sweep
+
+    geometry = [shot_geometry(SURVEY, j) for j in (3, 4)]
+    observed = record_survey(SURVEY, geometry, seed=1)
+    records = (len(geometry), SURVEY.record_samples, SURVEY.n_receivers)
+    gc.collect()
+    gc.disable()
+    try:
+        # on the CPU ``observed`` is a view of the recording's device buffer
+        before = {id(a) for a in jax.live_arrays()}
+        for _ in range(2):
+            res = run_sweep(SURVEY, geometry, observed, seed=1, slots=2,
+                            timeout=300.0)
+            assert res["all_done"]
+            assert not [a for a in jax.live_arrays()
+                        if a.shape == records and id(a) not in before]
+    finally:
+        gc.enable()

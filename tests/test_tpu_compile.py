@@ -11,6 +11,7 @@ and every test worker imports every test file.
 """
 
 import os
+import re
 
 import numpy as np
 import pytest
@@ -108,6 +109,21 @@ def test_seismic_member_step_compiles_for_v5e(one_chip):
     assert mem.temp_size_in_bytes < 16e9
 
 
+def test_seismic_member_step_runs_the_stencil_kernel_on_v5e(one_chip,
+                                                            monkeypatch):
+    """chip_smoke's 512 × 512 sweep on a TPU: both forwards of a member
+    (the trial and the observed) take the kernel over the 128 events, two
+    steps a loop iteration as every step is recorded, and no wavefield in
+    the kernel's 520 × 640 storage is copied."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    sources = jax.ShapeDtypeStruct((EVENTS,), jnp.int32, sharding=one_chip)
+    # a fresh function: the jnp path's trace of _member_misfits is cached
+    text = jax.jit(lambda s: _member_misfits(s)).lower(
+        sources).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 4
+    assert not re.search(r"f32\[128,520,640\]\{[^}]*\} copy\(", text)
+
+
 def test_sharded_sweep_with_sum_reduction_compiles_for_v5e(topo):
     from repro.fusion.engine import _apply_reduction
 
@@ -129,13 +145,11 @@ def test_sharded_sweep_with_sum_reduction_compiles_for_v5e(topo):
     assert "all-reduce" in compiled.as_text()
 
 
-def test_marmousi_sweep_compiles_for_v5e_with_the_model_an_argument(
-        one_chip):
+def _marmousi_sweep(one_chip):
     """The 8-shot Marmousi misfit sweep (2301 × 751 at 4 m, a 50-cell pad,
     5800 steps recorded every 8th at 96 moving receivers) as the fusion
-    engine composes it: forward → misfit vmapped over the shots, then the
-    masked Σ. The velocity model is an argument: a constant of the program
-    would put its 1.7 M values in the HLO text."""
+    engine composes it, compiled for one v5e chip: forward → misfit
+    vmapped over the shots, then the masked Σ, the model an argument."""
     from repro.apps.seismic.solver import SeismicConfig
     from repro.apps.seismic.workflow import shot_misfit, shot_synthetic
     from repro.fusion.engine import _apply_reduction
@@ -157,10 +171,32 @@ def test_marmousi_sweep_compiles_for_v5e_with_the_model_an_argument(
     def arg(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    compiled = jax.jit(sweep).lower(
+    return jax.jit(sweep).lower(
         arg((shots,), jnp.int32), arg((shots, receivers), jnp.int32),
         arg((shots, cfg.record_samples, receivers), jnp.float32),
         arg((cfg.nz, cfg.nx), jnp.float32), arg((shots,), jnp.bool_),
     ).compile()
+
+
+def test_marmousi_sweep_compiles_for_v5e_with_the_model_an_argument(
+        one_chip):
+    """The sweep with the jnp step. The velocity model is an argument: a
+    constant of the program would put its 1.7 M values in the HLO text."""
+    compiled = _marmousi_sweep(one_chip)
     assert compiled.memory_analysis().temp_size_in_bytes < 4e9
     assert len(compiled.as_text()) < 1 << 20
+
+
+def test_marmousi_sweep_runs_the_stencil_kernel_on_v5e(one_chip,
+                                                       monkeypatch):
+    """On a TPU the sweep's steps are the Pallas kernel, one call over the
+    8 shots a step: four calls in the loop bodies (a recorded step, a pair
+    of steps, the block's last step). The wavefields, in the kernel's
+    864 × 2432 storage, stay in HBM, so each step streams them once, and
+    the loop carries them without a copy."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    text = _marmousi_sweep(one_chip).as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 4
+    fields = re.findall(r"f32\[8,864,2432\]\{[^}]*\}", text)
+    assert fields and not any("S(1)" in f for f in fields)
+    assert not re.search(r"f32\[8,864,2432\]\{[^}]*\} copy\(", text)
